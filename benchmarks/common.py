@@ -1,13 +1,16 @@
 """Shared benchmark helpers: wall-clock timing of jitted callables and the
 TPU-v5e analytic latency model used to project paper figures from dry-run
-artifacts (this container has no TPU; wall-time benches run CPU-scale
-proxies, latency projections use the roofline constants)."""
+artifacts.  A wall-clock time is a time on whatever backend ran it, so
+every emitted row carries the device label (platform, device_kind, count);
+latency projections use the roofline constants and are analytic."""
 from __future__ import annotations
 
 import time
 from typing import Callable
 
 import jax
+
+from repro.launch.runtime import device_label
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -16,7 +19,9 @@ HBM_PER_CHIP = 16e9  # v5e
 
 
 def time_fn(fn: Callable, *args, iters: int = 20, warmup: int = 3) -> float:
-    """Median wall-time per call in microseconds (blocks on results)."""
+    """Median wall-time per call in microseconds (blocks on results), on
+    the device ``device_label()`` names — ``emit`` writes it beside every
+    number."""
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
     times = []
@@ -29,7 +34,7 @@ def time_fn(fn: Callable, *args, iters: int = 20, warmup: int = 3) -> float:
 
 
 def emit(name: str, us_per_call: float, derived: str) -> None:
-    print(f"{name},{us_per_call:.1f},{derived}")
+    print(f"{name},{us_per_call:.1f},{derived},{device_label()}")
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 from benchmarks.common import decode_latency_model, emit, min_gpus_to_fit, time_fn
 from repro.configs.base import count_active_params, count_params
 from repro.configs.registry import all_configs
+from repro.launch.runtime import enable_compile_cache
 
 
 def table3() -> None:
@@ -887,6 +888,11 @@ print(json.dumps({"total": total, "expert": expert,
 """
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # this process may hold the accelerator, and a chip belongs to one
+    # process: the child forces 8 fake CPU devices and measures placement
+    # (bytes per device), never time — keep this section out of chip runs
+    print("# ep_serving: placement measured in a child process on 8 fake CPU "
+          "devices (JAX_PLATFORMS=cpu)")
     r = subprocess.run([_sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
@@ -1067,7 +1073,8 @@ SECTIONS = {
 
 def main() -> None:
     picks = sys.argv[1:] or list(SECTIONS)
-    print("name,us_per_call,derived")
+    enable_compile_cache()
+    print("name,us_per_call,derived,device")
     for p in picks:
         SECTIONS[p]()
 

@@ -33,7 +33,7 @@ import math
 from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Report
 

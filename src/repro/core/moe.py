@@ -69,8 +69,9 @@ def experts_ffn(params: dict, xe: jax.Array, act: str, *, backend: str | None = 
 
     Quantized expert weights (MoQ, repro/quant) are handled transparently:
     the int8-per-channel SwiGLU layout takes the Pallas dequant-in-kernel
-    path on TPU (weights stream HBM→VMEM at 1 byte/param); other layouts
-    (int4, group-wise, non-swiglu acts) dequantize into the einsum path.
+    path on TPU (weights stream HBM→VMEM at 1 byte/param); "ref" dequantizes
+    into the einsum path.  Under "kernel" (the TPU default) any other
+    quantized layout raises instead of widening whole experts per call.
     ``backend`` ("kernel" | "ref") pins the quantized path per call —
     prefer it over the process-wide toggle below when jit caching matters.
     """
@@ -112,7 +113,14 @@ def _experts_ffn_quant(params: dict, xe: jax.Array, act: str, backend: str | Non
     mode = backend or QUANT_EXPERT_BACKEND[0]
     if mode is None:
         mode = "kernel" if jax.default_backend() == "tpu" else "ref"
-    if mode == "kernel" and act == "swiglu" and _check_kernel_compat(xe, wi, wg, wo):
+    if mode == "kernel":
+        if act != "swiglu" or not _check_kernel_compat(xe, wi, wg, wo):
+            raise ValueError(
+                "quantized capacity expert kernel takes int8 per-output-channel "
+                f"SwiGLU experts with block-divisible shapes; got act={act}, "
+                f"bits={wi.bits}, group_size={wi.group_size}, C={xe.shape[1]}, "
+                f"F={wi.shape[-1]} (backend='ref' runs the dequant reference)"
+            )
         from repro.kernels.ops import fused_expert_mlp_quant
 
         return fused_expert_mlp_quant(xe, wi, wg, wo)
@@ -146,10 +154,11 @@ def grouped_experts_ffn(
     """xg: [Ct, D] expert-sorted tile-padded tokens; te: [Ct/tile] tile ->
     expert map (core/dispatch_grouped.py layout) -> [Ct, D].
 
-    fp and quantized weights both route to the grouped Pallas kernel on TPU
-    (int8 AND int4 dequantize in VMEM — the grouped path is the first place
-    int4 gets a true in-kernel execution); elsewhere the gather-einsum
-    reference runs.
+    "kernel" (the TPU default) runs the grouped Pallas kernel for fp and
+    int8/int4 weights, with the layer's activation in the kernel, and raises
+    on a layout the kernel cannot take; "ref" (the default elsewhere) runs
+    the gather-einsum reference, which materializes every tile's expert
+    weights and is a correctness oracle, not a serving path.
     """
     from repro.kernels import expert_mlp_grouped as gk
 
@@ -159,18 +168,19 @@ def grouped_experts_ffn(
     mode = backend or GROUPED_EXPERT_BACKEND[0]
     if mode is None:
         mode = "kernel" if jax.default_backend() == "tpu" else "ref"
-    if mode == "kernel" and act == "swiglu":
-        if not quantized:
-            from repro.kernels.ops import fused_expert_mlp_grouped
+    if mode == "ref":
+        if quantized:
+            return gk.grouped_mlp_quant_ref(xg, te, wi, wg, wo, act)
+        return gk.grouped_mlp_ref(xg, te, wi, wg, wo, act)
+    reason = gk.grouped_kernel_unsupported(wi, wg, wo, act)
+    if reason is not None:
+        raise ValueError(
+            f"grouped expert kernel cannot take this layer: {reason} "
+            "(backend='ref' runs the gather-einsum reference)")
+    from repro.kernels.ops import fused_expert_mlp_grouped, fused_expert_mlp_grouped_quant
 
-            return fused_expert_mlp_grouped(xg, te, wi, wg, wo)
-        if gk._check_grouped_quant_compat(wi, wg, wo):
-            from repro.kernels.ops import fused_expert_mlp_grouped_quant
-
-            return fused_expert_mlp_grouped_quant(xg, te, wi, wg, wo)
-    if quantized:
-        return gk.grouped_mlp_quant_ref(xg, te, wi, wg, wo, act)
-    return gk.grouped_mlp_ref(xg, te, wi, wg, wo, act)
+    fused = fused_expert_mlp_grouped_quant if quantized else fused_expert_mlp_grouped
+    return fused(xg, te, wi, wg, wo, act=act)
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import FFNSpec, ModelConfig
 from repro.core.dispatch import combine_dense, dispatch_dense
 from repro.core.gating import expert_capacity, load_balance_loss, top_k_gating
-from repro.parallel.compat import axis_size, shard_map
 from repro.parallel.sharding import get_mesh
 
 EP_AXIS = "data"
@@ -86,7 +85,7 @@ def _moe_body(cfg: ModelConfig, spec: FFNSpec, mesh, hier: bool, x_loc, router, 
     B_loc, S, D = x_loc.shape
     E = spec.num_experts
     K = spec.top_k
-    ep = axis_size(EP_AXIS) * (axis_size("pod") if hier else 1)
+    ep = jax.lax.axis_size(EP_AXIS) * (jax.lax.axis_size("pod") if hier else 1)
     E_loc = E // ep
     T_loc = B_loc * S
     cap = expert_capacity(T_loc, E, K, spec.capacity_factor)
@@ -151,7 +150,7 @@ def _moe_body_allgather(cfg: ModelConfig, spec: FFNSpec, mesh, x_loc, router, wi
     decode iteration 1)."""
     B_loc, S, D = x_loc.shape
     E, K = spec.num_experts, spec.top_k
-    ep = axis_size(EP_AXIS)
+    ep = jax.lax.axis_size(EP_AXIS)
     E_loc = E // ep
     my_ep = jax.lax.axis_index(EP_AXIS)
 
@@ -231,7 +230,7 @@ def moe_layer_ep(cfg: ModelConfig, spec: FFNSpec, params: dict, x: jax.Array) ->
     else:
         body = partial(_moe_body, cfg, spec, mesh, hier)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, router_spec, wi_spec, wi_spec, wo_spec),

@@ -45,7 +45,6 @@ from repro.configs.base import FFNSpec, ModelConfig
 from repro.core.dispatch import combine_dense, dispatch_dense
 from repro.core.dispatch_grouped import GROUPED_TILE, grouped_layout
 from repro.core.gating import expert_capacity, load_balance_loss, load_balance_stats, top_k_gating
-from repro.parallel.compat import axis_size, shard_map
 from repro.parallel.sharding import get_mesh, get_rules
 
 
@@ -80,7 +79,7 @@ def _ep_rank(axes) -> jax.Array:
     so shard r owns experts [r*E_loc, (r+1)*E_loc)."""
     r = jnp.int32(0)
     for a in axes:
-        r = r * axis_size(a) + jax.lax.axis_index(a)
+        r = r * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return r
 
 
@@ -100,7 +99,7 @@ def _body_replicated_dense(cfg: ModelConfig, spec: FFNSpec, axes, x, router, wi,
     E, K = spec.num_experts, spec.top_k
     ep = 1
     for a in axes:
-        ep *= axis_size(a)
+        ep *= jax.lax.axis_size(a)
     E_loc = E // ep
     T = B * S
     cap = expert_capacity(T, E, K, spec.capacity_factor)
@@ -148,7 +147,7 @@ def _body_replicated_grouped(cfg: ModelConfig, spec: FFNSpec, axes, x, router, w
     E, K = spec.num_experts, spec.top_k
     ep = 1
     for a in axes:
-        ep *= axis_size(a)
+        ep *= jax.lax.axis_size(a)
     E_loc = E // ep
     T = B * S
     TK = T * K
@@ -200,7 +199,7 @@ def _body_a2a(cfg: ModelConfig, spec: FFNSpec, axes, x_loc, router, wi, wg, wo):
     E, K = spec.num_experts, spec.top_k
     ep = 1
     for a in axes:
-        ep *= axis_size(a)
+        ep *= jax.lax.axis_size(a)
     E_loc = E // ep
     cap = expert_capacity(T_loc, E, K, spec.capacity_factor)
 
@@ -274,7 +273,7 @@ def moe_layer_ep_serve(
         body = (
             _body_replicated_grouped if kernel == "grouped" else _body_replicated_dense
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(body, cfg, spec, axes),
             mesh=mesh,
             in_specs=(rep, P(None, None), w_spec, w_spec, w_spec),
@@ -291,7 +290,7 @@ def moe_layer_ep_serve(
     if Tp != T:
         xs = jnp.concatenate([xs, jnp.zeros((Tp - T, D), xs.dtype)])
     tok_spec = P(axes if len(axes) > 1 else axes[0], None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_body_a2a, cfg, spec, axes),
         mesh=mesh,
         in_specs=(tok_spec, rep, w_spec, w_spec, w_spec),

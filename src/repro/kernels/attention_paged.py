@@ -1,19 +1,25 @@
-"""Paged decode attention (TPU Pallas, validated in interpret mode): one
-query token attends over K/V *pages* gathered through a block table.
+"""Paged decode attention (TPU Pallas): one query token attends over K/V
+*pages* gathered through a block table.
 
-The KV cache lives in HBM as a shared page pool ``[n_pages+1, page_size,
-H_kv, dh]`` (last page = trash, never mapped); each sequence's history is the
-pages named by its block-table row.  The grid is (batch, kv-head, table
-entry) with the table **scalar-prefetched** so the BlockSpec index map can
-pick each K/V page data-dependently — the DMA engine streams exactly the
-pages a sequence owns, nothing else, and the kernel never materializes a
-gathered contiguous copy of the cache.  The page axis is innermost
-(sequential on TPU), so the online-softmax running max / normalizer /
-accumulator live in VMEM scratch across pages, flash-attention style.
+The KV cache lives in HBM as a shared page pool ``[n_pages+1, H_kv,
+page_size, dh]`` (last page = trash, never mapped); each sequence's history
+is the pages named by its block-table row.  The grid is (batch, table entry)
+with the table **scalar-prefetched** so the BlockSpec index map can pick
+each page data-dependently: one grid step DMAs one whole page, every kv-head
+at once (a contiguous ``[H_kv, page_size, dh]`` block), and the kernel never
+materializes a gathered contiguous copy of the cache.  The page axis is
+innermost (sequential on TPU), so the online-softmax running max /
+normalizer / accumulator live in VMEM scratch across pages,
+flash-attention style, with the kv-heads as the batch dim of the dots.
+
+The layout is chosen for Mosaic's tiling rule (a block's last two dims are
+multiples of (8, 128) or the array's own): ``(page_size, dh)`` trails every
+K/V block, positions ride as ``[Pt, 1, page_size]`` blocks, and the query
+positions are scalar-prefetched with the table.
 
 Composes with the int8 KV cache (kernels/attention_quant.py): when the pool
 is quantized, each page's int8 K/V tile is widened and rescaled by its
-per-(timestep, head) f32 scales *in VMEM* right before the dot — pages then
+per-(head, timestep) f32 scales *in VMEM* right before the dot — pages then
 cost ~1 byte/entry of HBM traffic on top of the fragmentation win.
 
 Masking (unmapped-page validity, causality, sliding window) is computed
@@ -32,6 +38,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# batched-over-heads contractions: [H, M, d] x [H, N, d] -> [H, M, N] and
+# [H, M, N] x [H, N, d] -> [H, M, d]
+_QK = (((2,), (2,)), ((0,), (0,)))
+_PV = (((2,), (1,)), ((0,), (0,)))
+
 
 def _soft_cap(s, cap: float):
     if cap and cap > 0.0:
@@ -39,17 +50,52 @@ def _soft_cap(s, cap: float):
     return s
 
 
-def _paged_kernel(table_ref, *refs, scale, causal, window, softcap, nt, ps, quantized):
-    """Grid (B, Hkv, nt); refs layout depends on ``quantized`` (scales
-    present or not).  Scratch: running max / normalizer / accumulator."""
+def gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
+    """Pool leaf + clamped table -> the contiguous history the table names.
+
+    ``[Pt, Hkv, ps, ...]`` K/V/scale leaves give ``[*table.shape[:-1],
+    nt*ps, Hkv, ...]``; the ``[Pt, ps]`` position leaf gives
+    ``[*table.shape[:-1], nt*ps]``."""
+    g = pool[table]  # [..., nt, (Hkv,) ps, ...]
+    lead = table.shape[:-1]
+    nt = table.shape[-1]
+    if pool.ndim >= 4:
+        g = jnp.swapaxes(g, table.ndim, table.ndim + 1)  # [..., nt, ps, Hkv, ...]
+        return g.reshape(lead + (nt * pool.shape[2],) + g.shape[table.ndim + 1:])
+    return g.reshape(lead + (nt * pool.shape[1],) + g.shape[table.ndim + 1:])
+
+
+def online_softmax_update(m_ref, l_ref, acc_ref, s, valid, v):
+    """One flash-attention step over a key tile, batched over kv-heads.
+
+    s: [H, M, N] f32 scores; valid: mask broadcastable to s; v: [H, N, dh]
+    f32.  Masked entries are zeroed explicitly: on a fully-masked tile seen
+    before any valid key the running max is still NEG_INF, and
+    exp(NEG_INF - NEG_INF) == 1 would count every masked key into the
+    normalizer."""
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, _PV, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _paged_kernel(qpos_ref, table_ref, *refs, scale, causal, window, softcap, nt, quantized):
+    """Grid (B, nt); refs layout depends on ``quantized`` (scales present or
+    not).  Scratch: running max / normalizer [Hkv, G, 1], accumulator
+    [Hkv, G, dh]."""
+    del table_ref  # consumed by the index maps
     if quantized:
-        (q_ref, qpos_ref, kq_ref, ks_ref, vq_ref, vs_ref, kpos_ref,
+        (q_ref, kq_ref, ks_ref, vq_ref, vs_ref, kpos_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
     else:
-        (q_ref, qpos_ref, kq_ref, vq_ref, kpos_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
-    it = pl.program_id(2)
+        (q_ref, kq_ref, vq_ref, kpos_ref, o_ref, m_ref, l_ref, acc_ref) = refs
+    b = pl.program_id(0)
+    it = pl.program_id(1)
 
     @pl.when(it == 0)
     def _init():
@@ -57,43 +103,27 @@ def _paged_kernel(table_ref, *refs, scale, causal, window, softcap, nt, ps, quan
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    G, dh = q_ref.shape[-2], q_ref.shape[-1]
-    q = q_ref[...].reshape(G, dh).astype(jnp.float32)
-    k = kq_ref[...].reshape(ps, dh).astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[...].reshape(ps, 1)  # dequantize the page in VMEM
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, ps]
-    s = _soft_cap(s, softcap)
+    q = q_ref[0].astype(jnp.float32)  # [Hkv, G, dh]
+    k = kq_ref[0].astype(jnp.float32)  # [Hkv, ps, dh]
+    v = vq_ref[0].astype(jnp.float32)
+    if quantized:  # dequantize the page in VMEM
+        k = k * ks_ref[0]
+        v = v * vs_ref[0]
+    s = jax.lax.dot_general(q, k, _QK, preferred_element_type=jnp.float32) * scale
+    s = _soft_cap(s, softcap)  # [Hkv, G, ps]
 
-    kp = kpos_ref[...].reshape(1, ps)  # absolute positions, -1 = empty
-    qp = qpos_ref[0, 0]
+    kp = kpos_ref[0]  # [1, ps] absolute positions, -1 = empty
+    qp = qpos_ref[b]
     valid = kp >= 0
     if causal:
         valid = valid & (kp <= qp)
     if window > 0:
         valid = valid & (qp - kp < window)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    # Zero masked entries explicitly: on a fully-masked tile seen before any
-    # valid key the running max is still NEG_INF, and exp(NEG_INF - NEG_INF)
-    # == 1 would count every masked key into the normalizer.
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)  # [G, ps]
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    v = vq_ref[...].reshape(ps, dh).astype(jnp.float32)
-    if quantized:
-        v = v * vs_ref[...].reshape(ps, 1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    online_softmax_update(m_ref, l_ref, acc_ref, s, valid[None], v)
 
     @pl.when(it == nt - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -102,10 +132,10 @@ def _paged_kernel(table_ref, *refs, scale, causal, window, softcap, nt, ps, quan
 )
 def paged_decode_attention(
     q: jax.Array,      # [B, Hkv, G, dh] — one decode token, grouped per kv-head
-    kq: jax.Array,     # [Pt, ps, Hkv, dh] page pool (int8 if quantized, else fp)
-    ks,                # [Pt, ps, Hkv, 1] f32 scales, or None (fp pool)
-    vq: jax.Array,     # [Pt, ps, Hkv, dh]
-    vs,                # [Pt, ps, Hkv, 1] or None
+    kq: jax.Array,     # [Pt, Hkv, ps, dh] page pool (int8 if quantized, else fp)
+    ks,                # [Pt, Hkv, ps, 1] f32 scales, or None (fp pool)
+    vq: jax.Array,     # [Pt, Hkv, ps, dh]
+    vs,                # [Pt, Hkv, ps, 1] or None
     kpos: jax.Array,   # [Pt, ps] int32 — absolute position per pool entry, -1 empty
     table: jax.Array,  # [B, nt] int32 — page ids; MUST be pre-clamped: -1 -> Pt-1
     qpos: jax.Array,   # [B, 1] int32 — the query token's absolute position
@@ -114,9 +144,12 @@ def paged_decode_attention(
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns [B, Hkv, G, dh] attention output in q.dtype.
+
+    ``interpret`` has no default: True runs the kernel body in the Pallas
+    interpreter (any backend), False compiles it with Mosaic (TPU only).
 
     Caller contract (``tests/test_paged.py::TestPagedKernel`` checks the
     masking consequences against the einsum ref):
@@ -132,43 +165,39 @@ def paged_decode_attention(
         trash-only rows (inactive slots) return garbage-but-finite output
         that the scheduler discards."""
     B, Hkv, G, dh = q.shape
-    ps = kq.shape[1]
+    Pt, _, ps, _ = kq.shape
     nt = table.shape[1]
     quantized = ks is not None
 
     kern = functools.partial(
         _paged_kernel,
         scale=scale, causal=causal, window=window, softcap=softcap,
-        nt=nt, ps=ps, quantized=quantized,
+        nt=nt, quantized=quantized,
     )
-    # index maps get the prefetched table ref appended; each (b, ·, t) step
-    # DMAs page table[b, t] of the pool straight into VMEM
-    page = lambda b, h, t, tref: (tref[b, t], 0, h, 0)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, dh), lambda b, h, t, tref: (b, h, 0, 0)),   # q
-        pl.BlockSpec((1, 1), lambda b, h, t, tref: (b, 0)),                # qpos
-        pl.BlockSpec((1, ps, 1, dh), page),                                # k page
-    ]
-    args = [q, qpos, kq]
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, ps, 1, 1), page))                 # k scales
-        args.append(ks)
-    in_specs.append(pl.BlockSpec((1, ps, 1, dh), page))                    # v page
-    args.append(vq)
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, ps, 1, 1), page))                 # v scales
-        args.append(vs)
-    in_specs.append(pl.BlockSpec((1, ps), lambda b, h, t, tref: (tref[b, t], 0)))  # pos
+    # index maps get the prefetched (qpos, table) refs appended; each (b, t)
+    # step DMAs page table[b, t] of the pool straight into VMEM
+    page = lambda b, t, qp, tref: (tref[b, t], 0, 0, 0)
+    row = lambda b, t, qp, tref: (b, 0, 0, 0)
+    in_specs = [pl.BlockSpec((1, Hkv, G, dh), row)]                        # q
+    args = [q]
+    for pool, scales in ((kq, ks), (vq, vs)):
+        in_specs.append(pl.BlockSpec((1, Hkv, ps, dh), page))              # page
+        args.append(pool)
+        if quantized:
+            in_specs.append(pl.BlockSpec((1, Hkv, ps, 1), page))           # scales
+            args.append(scales)
+    in_specs.append(pl.BlockSpec((1, 1, ps), lambda b, t, qp, tref: (tref[b, t], 0, 0)))
+    args.append(kpos.reshape(Pt, 1, ps))                                   # positions
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, Hkv, nt),
+        num_scalar_prefetch=2,
+        grid=(B, nt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, dh), lambda b, h, t, tref: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, dh), row),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),      # running max
-            pltpu.VMEM((G,), jnp.float32),      # running normalizer
-            pltpu.VMEM((G, dh), jnp.float32),   # output accumulator
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # running max
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # running normalizer
+            pltpu.VMEM((Hkv, G, dh), jnp.float32),   # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -176,7 +205,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
         interpret=interpret,
-    )(table, *args, kpos)
+    )(qpos.reshape(B).astype(jnp.int32), table.astype(jnp.int32), *args)
 
 
 def paged_decode_attention_ref(
@@ -184,13 +213,7 @@ def paged_decode_attention_ref(
 ):
     """Pure-jnp oracle: gather the mapped pages into a contiguous [B, T]
     view (T = nt * ps), dequantize if needed, masked f32 softmax."""
-    B, Hkv, G, dh = q.shape
-    ps = kq.shape[1]
-
-    def gather(pool):  # [Pt, ps, ...] -> [B, nt*ps, ...]
-        g = pool[table]  # table pre-clamped: -1 -> trash page
-        return g.reshape((B, table.shape[1] * ps) + g.shape[3:])
-
+    gather = lambda pool: gather_pages(pool, table)  # pre-clamped table
     k = gather(kq).astype(jnp.float32)
     v = gather(vq).astype(jnp.float32)
     if ks is not None:

@@ -104,7 +104,7 @@ def decode_attention_quant(
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: bool,
     block_t: int = BLOCK_T,
 ) -> jax.Array:
     """Returns [B, Hkv, G, dh] attention output in q.dtype."""

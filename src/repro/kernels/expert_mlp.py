@@ -42,7 +42,7 @@ def expert_mlp_kernel(
     wg: jax.Array,  # [E, D, F]
     wo: jax.Array,  # [E, F, D]
     *,
-    interpret: bool = True,
+    interpret: bool,
     block_c: int = BLOCK_C,
     block_f: int = BLOCK_F,
 ) -> jax.Array:

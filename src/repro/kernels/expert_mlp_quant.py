@@ -52,7 +52,7 @@ def expert_mlp_quant_kernel(
     wo_q: jax.Array,  # [E, F, D] int8
     wo_s: jax.Array,  # [E, 1, D] f32
     *,
-    interpret: bool = True,
+    interpret: bool,
     block_c: int = BLOCK_C,
     block_f: int = BLOCK_F,
 ) -> jax.Array:
@@ -103,7 +103,7 @@ def expert_mlp_quant(
     wg: QuantizedArray,
     wo: QuantizedArray,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel entry from QuantizedArray leaves (int8 per-channel layout)."""
     if not _check_kernel_compat(xe, wi, wg, wo):

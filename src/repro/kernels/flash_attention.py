@@ -68,7 +68,7 @@ def flash_attention(
     *,
     scale: float,
     causal: bool = True,
-    interpret: bool = True,
+    interpret: bool,
     block_q: int = BLOCK_Q,
     block_k: int = BLOCK_K,
 ) -> jax.Array:

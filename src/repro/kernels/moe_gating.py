@@ -71,7 +71,7 @@ def gating_kernel(
     capacity: int,
     *,
     normalize: bool = True,
-    interpret: bool = True,
+    interpret: bool,
     block_t: int = BLOCK_T,
 ):
     """Fused gating.  Returns (expert_idx [T,K], combine_w [T,K],

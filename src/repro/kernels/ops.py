@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On TPU the kernels compile natively; on CPU (this container) they execute in
-``interpret=True`` mode, which runs the kernel body in Python for
-correctness validation against ref.py.  ``use_pallas_gating()`` returns a
-Gating namedtuple so the kernel drops into core/moe.py transparently.
+The kernels themselves take ``interpret`` with no default; these wrappers
+are where the mode is decided: compiled by Mosaic on a TPU backend, the
+Pallas interpreter elsewhere (correctness validation against the refs).
+``fused_gating()`` returns a Gating namedtuple so the kernel drops into
+core/moe.py transparently.
 """
 from __future__ import annotations
 
@@ -36,21 +37,21 @@ def fused_expert_mlp_quant(xe, wi, wg, wo):
     return expert_mlp_quant(xe, wi, wg, wo, interpret=_interpret())
 
 
-def fused_expert_mlp_grouped(xg, te, wi, wg, wo):
+def fused_expert_mlp_grouped(xg, te, wi, wg, wo, *, act):
     """Dropless grouped expert MLP: ``xg`` [Ct, D] expert-sorted tile-padded
     tokens, ``te`` the scalar-prefetched tile->expert map
-    (kernels/expert_mlp_grouped.py)."""
+    (kernels/expert_mlp_grouped.py); ``wg`` only for act="swiglu"."""
     from repro.kernels.expert_mlp_grouped import grouped_mlp_kernel
 
-    return grouped_mlp_kernel(xg, te, wi, wg, wo, interpret=_interpret())
+    return grouped_mlp_kernel(xg, te, wi, wg, wo, act=act, interpret=_interpret())
 
 
-def fused_expert_mlp_grouped_quant(xg, te, wi, wg, wo):
+def fused_expert_mlp_grouped_quant(xg, te, wi, wg, wo, *, act):
     """Dropless grouped expert MLP over int8/int4 QuantizedArrays — tiles
     dequantized (int4: nibble-unpacked) in VMEM before each MXU dot."""
     from repro.kernels.expert_mlp_grouped import grouped_mlp_quant
 
-    return grouped_mlp_quant(xg, te, wi, wg, wo, interpret=_interpret())
+    return grouped_mlp_quant(xg, te, wi, wg, wo, act=act, interpret=_interpret())
 
 
 def fused_decode_attention_quant(q, kq, ks, vq, vs, kpos, qpos, *, scale, causal, window, softcap):
@@ -80,18 +81,18 @@ def fused_decode_attention_paged(q, kq, ks, vq, vs, kpos, table, qpos, *, scale,
     )
 
 
-def fused_prefill_attention_paged(q, kq, ks, vq, vs, kpos, table, qpos, ck, cv,
+def fused_prefill_attention_paged(q, kq, ks, vq, vs, kpos, tables, qpos, ck, cv,
                                   *, scale, causal, window, softcap):
-    """Chunked-prefill attention over a paged KV pool: one chunk of prompt
-    queries attends to the sequence's already-written pages (earlier chunks,
-    shared prefix pages) via the scalar-prefetched block table PLUS its own
-    in-flight fp K/V (kernels/attention_prefill_paged.py).  ``table`` must be
-    pre-clamped (-1 entries -> trash page); the pool must be pre-write (the
-    chunk's own positions still carry ``pos == -1``)."""
+    """Chunked-prefill attention over a paged KV pool: each row's chunk of
+    prompt queries attends to that sequence's already-written pages (earlier
+    chunks, shared prefix pages) via the scalar-prefetched block tables PLUS
+    its own in-flight fp K/V (kernels/attention_prefill_paged.py).
+    ``tables`` must be pre-clamped (-1 entries -> trash page); the pool must
+    be pre-write (the chunk's own positions still carry ``pos == -1``)."""
     from repro.kernels.attention_prefill_paged import paged_prefill_attention
 
     return paged_prefill_attention(
-        q, kq, ks, vq, vs, kpos, table, qpos, ck, cv,
+        q, kq, ks, vq, vs, kpos, tables, qpos, ck, cv,
         scale=scale, causal=causal, window=window, softcap=softcap,
         interpret=_interpret(),
     )

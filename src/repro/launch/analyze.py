@@ -290,6 +290,11 @@ def _reexec_ep(args) -> int:
         cmd.append("--show-suppressed")
     if args.skip:
         cmd += ["--skip", *args.skip]
+    # the parent already holds the accelerator (a chip belongs to one
+    # process), so the child runs on fake CPU devices — this is a trace-time
+    # gate, nothing it prints is a device measurement
+    print(f"[analyze --ep-only: child process on {_EP_DEVICES} fake CPU devices "
+          "(JAX_PLATFORMS=cpu)]", flush=True)
     proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
     sys.stdout.write(proc.stdout)
     if proc.returncode and proc.stderr:

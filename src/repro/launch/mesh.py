@@ -3,7 +3,7 @@ never touches jax device state.  Single pod: (data=16, model=16) = 256 chips
 of TPU v5e; multi-pod adds a leading 'pod' axis (2 pods = 512 chips)."""
 from __future__ import annotations
 
-from repro.parallel.compat import make_mesh
+from repro.parallel.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.checkpoint import ckpt
 from repro.configs.registry import get_config, make_reduced
+from repro.launch.runtime import device_label, enable_compile_cache
 from repro.models.model import init_params
 from repro.obs import Obs
 from repro.serving.engine import Engine, EngineConfig, Request
@@ -171,6 +172,8 @@ def main() -> None:
         if args.spec_k < 1:
             ap.error(f"--spec-k must be >= 1, got {args.spec_k}")
 
+    enable_compile_cache()
+    print(f"device: {device_label()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)
@@ -241,9 +244,9 @@ def main() -> None:
         if cfg.moe_impl == "grouped" and args.quant_group_size:
             print(f"NB: the grouped Pallas kernel dequantizes per-output-"
                   f"channel scales in VMEM; group_size="
-                  f"{args.quant_group_size} scales take the dequant-ref "
-                  "path (experts re-widened per call — drop "
-                  "--quant-group-size to keep the kernel)")
+                  f"{args.quant_group_size} scales run only through the "
+                  "dequant reference off the TPU, and raise on it — drop "
+                  "--quant-group-size to keep the kernel")
     elif args.ckpt:
         params, _ = ckpt.load(args.ckpt, params)
 

@@ -18,6 +18,7 @@ import jax
 from repro.checkpoint import ckpt
 from repro.configs.registry import get_config, make_reduced
 from repro.data.pipeline import data_stream
+from repro.launch.runtime import device_label, enable_compile_cache
 from repro.training.trainer import TrainConfig, train_loop
 
 
@@ -34,6 +35,8 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--moe-impl", default=None, choices=[None, "einsum", "dense", "ep"])
     args = ap.parse_args()
+    enable_compile_cache()
+    print(f"device: {device_label()}")
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttnSpec, ModelConfig
+from repro.kernels.attention_paged import gather_pages
 from repro.models.modules import apply_rope, dense_init, init_rmsnorm, rmsnorm, softcap
 from repro.parallel.sharding import shard_hint
 from repro.quant.kv import QuantizedKV, kv_quantize_values, materialize_kv
@@ -92,7 +93,7 @@ def spec_is_paged(spec: AttnSpec) -> bool:
 
 
 def init_paged_kv_cache(n_pages: int, page_size: int, n_kv: int, head_dim: int, dtype, *, kv_bits: int = 0) -> dict:
-    """Shared page-pool KV cache: ``[n_pages + 1, page_size, n_kv, head_dim]``
+    """Shared page-pool KV cache: ``[n_pages + 1, n_kv, page_size, head_dim]``
     with NO batch axis — sequences own pages through per-slot block tables
     (serving/kv_pool.py) instead of reserving a contiguous capacity row.
 
@@ -105,8 +106,12 @@ def init_paged_kv_cache(n_pages: int, page_size: int, n_kv: int, head_dim: int, 
 
     ``kv_bits=8`` stores pages as int8 :class:`~repro.quant.kv.QuantizedKV`
     — the two serving memory levers compose: ~4x fewer bytes per cache
-    token × fragmentation-free packing of those tokens."""
-    shape = (n_pages + 1, page_size, n_kv, head_dim)
+    token × fragmentation-free packing of those tokens.
+
+    Heads lead the page so one page of every head is one contiguous block
+    whose trailing ``(page_size, head_dim)`` dims are what the Pallas page
+    kernels' BlockSpecs tile (kernels/attention_paged.py)."""
+    shape = (n_pages + 1, n_kv, page_size, head_dim)
     if kv_bits == 8:
         k = QuantizedKV.zeros(shape, dtype)
         v = QuantizedKV.zeros(shape, dtype)
@@ -245,6 +250,23 @@ def set_paged_backend(mode) -> None:
     jax.clear_caches()
 
 
+def _over_slots(kernel, slot_args: tuple, pool_args: tuple):
+    """``kernel(slot_args, pool_args)`` for a Pallas page kernel.  Mosaic
+    kernels are not partitioned automatically, so under a serving mesh the
+    call is a shard_map: each device runs the kernel on its shard of the
+    slot-major arguments (leading dim, the "batch" rule axes when they
+    divide it) against the whole replicated page pool."""
+    from repro.parallel.sharding import get_mesh, spec
+    from jax.sharding import PartitionSpec as P
+
+    mesh = get_mesh()
+    if mesh is None:
+        return kernel(slot_args, pool_args)
+    rows = P(spec("batch", shape=slot_args[0].shape[:1])[0])
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(rows, P()), out_specs=rows,
+                         check_vma=False)(slot_args, pool_args)
+
+
 def _paged_clamp_table(table: jax.Array, n_pages_total: int) -> jax.Array:
     """-1 (unmapped) entries -> the trash page, whose pos is pinned at -1."""
     return jnp.where(table < 0, n_pages_total - 1, table).astype(jnp.int32)
@@ -261,18 +283,12 @@ def _paged_cache_write_decode(cache: dict, k_new, v_new, row_pos, table) -> dict
     entry = row_pos.astype(jnp.int32) // ps
     offs = row_pos.astype(jnp.int32) % ps
     pages = _paged_clamp_table(table[rows, entry], Pt)
-    write = lambda buf, vals: buf.at[pages, offs].set(vals[:, 0])
+    write = lambda buf, vals: buf.at[pages, :, offs].set(vals[:, 0])
     k = _write_kv(cache["k"], k_new, write)
     v = _write_kv(cache["v"], v_new, write)
     pos_val = jnp.where(pages == Pt - 1, -1, row_pos.astype(jnp.int32))
     pos = cache["pos"].at[pages, offs].set(pos_val)
     return {"k": k, "v": v, "pos": pos}
-
-
-def _paged_gather(pool, table):
-    """[Pt, ps, ...] pool + [B, nt] clamped table -> [B, nt*ps, ...]."""
-    g = pool[table]
-    return g.reshape((table.shape[0], table.shape[1] * pool.shape[1]) + g.shape[3:])
 
 
 def _paged_cache_write_chunk(cache: dict, k_new, v_new, positions, table_row) -> dict:
@@ -298,7 +314,7 @@ def _paged_cache_write_chunk(cache: dict, k_new, v_new, positions, table_row) ->
     pages = _paged_clamp_table(table_row[entry], Pt)
     already = cache["pos"][pages, offs] == pos  # shared-prefix entries
     pages = jnp.where(already, Pt - 1, pages)
-    write = lambda buf, vals: buf.at[pages, offs].set(vals[0])
+    write = lambda buf, vals: buf.at[pages, :, offs].set(vals[0])
     k = _write_kv(cache["k"], k_new, write)
     v = _write_kv(cache["v"], v_new, write)
     pos_val = jnp.where(pages == Pt - 1, -1, pos)
@@ -327,7 +343,7 @@ def _paged_cache_write_chunk_batched(cache: dict, k_new, v_new, positions, table
     pages = jnp.where(already | ~valid, Pt - 1, pages)
     flat_p = pages.reshape(-1)
     flat_o = offs.reshape(-1)
-    write = lambda buf, vals: buf.at[flat_p, flat_o].set(
+    write = lambda buf, vals: buf.at[flat_p, :, flat_o].set(
         vals.reshape((B * C,) + vals.shape[2:])
     )
     k = _write_kv(cache["k"], k_new, write)
@@ -340,9 +356,14 @@ def _paged_cache_write_chunk_batched(cache: dict, k_new, v_new, positions, table
 
 
 def _paged_prefill_chunk_attend_batched(q, k, v, cache: dict, positions, tables, spec: AttnSpec, scale: float):
-    """Multi-slot variant of ``_paged_prefill_chunk_attend``: each row's chunk
-    queries attend over that row's pages ++ its own in-flight K/V.  q/k/v:
-    [B, C, ...]; positions [B, C] (-1 invalid); tables [B, max_pages].  Rows
+    """Chunk queries attend over (already-written pool pages: earlier chunks
+    + shared prefix, read in place) ++ (the chunk's own in-flight fp K/V,
+    causal), one row per mid-prefill slot; ``cache`` is the PRE-write pool.
+    q/k/v: [B, C, ...]; positions [B, C] (-1 invalid); tables [B, max_pages].
+    Pool keys at positions >= the chunk start are masked out: when a
+    shared-prefix admission recomputes the prefix (archs with window rings /
+    SSM state), those positions are live in the shared pages AND in flight —
+    the in-flight copy is the single source, counted once.  Rows
     mask their pool history at positions >= their OWN chunk start
     (``positions[:, 0]``); invalid queries see an all-masked score row —
     finite uniform softmax garbage that the caller's active-mask merge and
@@ -359,91 +380,41 @@ def _paged_prefill_chunk_attend_batched(q, k, v, cache: dict, positions, tables,
     if mode == "kernel":
         from repro.kernels.ops import fused_prefill_attention_paged
 
-        # statically unrolled per-row kernel launches — all inside the ONE
-        # jitted batched-prefill call (a single host dispatch per tick)
+        # one kernel launch covers every row (grid axis 0)
         if quant:
-            args = (cache["k"].q, cache["k"].scale, cache["v"].q, cache["v"].scale)
+            pool = (cache["k"].q, cache["k"].scale, cache["v"].q, cache["v"].scale)
         else:
-            args = (cache["k"], None, cache["v"], None)
-        ys = []
-        for b in range(B):
-            qg = q[b].reshape(C, Hkv, H // Hkv, dh)
-            ys.append(fused_prefill_attention_paged(
-                qg, *args, cache["pos"], tbl[b], positions[b], k[b], v[b],
+            pool = (cache["k"], None, cache["v"], None)
+
+        def kernel(rows, pool):
+            qg, tb, qp, kc, vc = rows
+            kq, ks, vq, vs, kpos = pool
+            return fused_prefill_attention_paged(
+                qg, kq, ks, vq, vs, kpos, tb, qp, kc, vc,
                 scale=scale, causal=spec.causal, window=window,
                 softcap=spec.logit_softcap,
-            ))
-        return jnp.stack(ys).reshape(B, C, H, dh)
+            )
+
+        y = _over_slots(kernel, (q.reshape(B, C, Hkv, H // Hkv, dh), tbl, positions, k, v),
+                        pool + (cache["pos"],))
+        return y.reshape(B, C, H, dh)
     if quant:
         kh = materialize_kv(QuantizedKV(
-            _paged_gather(cache["k"].q, tbl), _paged_gather(cache["k"].scale, tbl),
+            gather_pages(cache["k"].q, tbl), gather_pages(cache["k"].scale, tbl),
             cache["k"].orig_dtype,
         ))
         vh = materialize_kv(QuantizedKV(
-            _paged_gather(cache["v"].q, tbl), _paged_gather(cache["v"].scale, tbl),
+            gather_pages(cache["v"].q, tbl), gather_pages(cache["v"].scale, tbl),
             cache["v"].orig_dtype,
         ))
     else:
-        kh = _paged_gather(cache["k"], tbl)
-        vh = _paged_gather(cache["v"], tbl)
+        kh = gather_pages(cache["k"], tbl)
+        vh = gather_pages(cache["v"], tbl)
     kcat = jnp.concatenate([kh.astype(k.dtype), k], axis=1)
     vcat = jnp.concatenate([vh.astype(v.dtype), v], axis=1)
-    hist_pos = _paged_gather(cache["pos"], tbl)  # [B, nt*ps]
+    hist_pos = gather_pages(cache["pos"], tbl)  # [B, nt*ps]
     start = positions[:, :1]  # per-row chunk start (-1 rows mask everything)
     hist_pos = jnp.where(hist_pos >= start, -1, hist_pos)  # pool = strictly pre-chunk
-    k_pos = jnp.concatenate([hist_pos, positions], axis=1)
-    mask = _window_causal_mask(positions, k_pos, window, spec.causal)
-    return _sdpa(q, kcat, vcat, mask, scale, spec.logit_softcap)
-
-
-def _paged_prefill_chunk_attend(q, k, v, cache: dict, positions, table_row, spec: AttnSpec, scale: float):
-    """Chunk queries attend over (already-written pool pages: earlier chunks
-    + shared prefix, read in place) ++ (the chunk's own in-flight fp K/V,
-    causal).  q/k/v: [1, C, ...]; ``cache`` is the PRE-write pool.  Pool keys
-    at positions >= the chunk start are masked out: when a shared-prefix
-    admission recomputes the prefix (archs with window rings / SSM state),
-    those positions are live in the shared pages AND in flight — the
-    in-flight copy is the single source, counted once."""
-    mode = PAGED_BACKEND[0]
-    if mode is None:
-        mode = "kernel" if jax.default_backend() == "tpu" else "ref"
-    window = spec.window if spec.kind == "local" else 0
-    Pt = cache["pos"].shape[0]
-    tbl = _paged_clamp_table(table_row, Pt)
-    quant = isinstance(cache["k"], QuantizedKV)
-    B, C, H, dh = q.shape
-    Hkv = k.shape[2]
-    if mode == "kernel":
-        from repro.kernels.ops import fused_prefill_attention_paged
-
-        qg = q[0].reshape(C, Hkv, H // Hkv, dh)
-        if quant:
-            args = (cache["k"].q, cache["k"].scale, cache["v"].q, cache["v"].scale)
-        else:
-            args = (cache["k"], None, cache["v"], None)
-        y = fused_prefill_attention_paged(
-            qg, *args, cache["pos"], tbl, positions[0], k[0], v[0],
-            scale=scale, causal=spec.causal, window=window,
-            softcap=spec.logit_softcap,
-        )
-        return y.reshape(1, C, H, dh)
-    tbl2 = tbl[None]  # [1, nt]
-    if quant:
-        kh = materialize_kv(QuantizedKV(
-            _paged_gather(cache["k"].q, tbl2), _paged_gather(cache["k"].scale, tbl2),
-            cache["k"].orig_dtype,
-        ))
-        vh = materialize_kv(QuantizedKV(
-            _paged_gather(cache["v"].q, tbl2), _paged_gather(cache["v"].scale, tbl2),
-            cache["v"].orig_dtype,
-        ))
-    else:
-        kh = _paged_gather(cache["k"], tbl2)
-        vh = _paged_gather(cache["v"], tbl2)
-    kcat = jnp.concatenate([kh.astype(k.dtype), k], axis=1)
-    vcat = jnp.concatenate([vh.astype(v.dtype), v], axis=1)
-    hist_pos = _paged_gather(cache["pos"], tbl2)
-    hist_pos = jnp.where(hist_pos >= positions[0, 0], -1, hist_pos)  # pool = strictly pre-chunk
     k_pos = jnp.concatenate([hist_pos, positions], axis=1)
     mask = _window_causal_mask(positions, k_pos, window, spec.causal)
     return _sdpa(q, kcat, vcat, mask, scale, spec.logit_softcap)
@@ -504,31 +475,37 @@ def _paged_decode_attend(q, cache: dict, row_pos, table, spec: AttnSpec, scale: 
         from repro.kernels.ops import fused_decode_attention_paged
 
         B, S, H, dh = q.shape
-        Hkv = cache["k"].shape[2]
+        Hkv = cache["k"].shape[1]  # pool leaf: [Pt, Hkv, ps, dh]
         qg = q[:, 0].reshape(B, Hkv, H // Hkv, dh)
         if quant:
-            args = (cache["k"].q, cache["k"].scale, cache["v"].q, cache["v"].scale)
+            pool = (cache["k"].q, cache["k"].scale, cache["v"].q, cache["v"].scale)
         else:
-            args = (cache["k"], None, cache["v"], None)
-        y = fused_decode_attention_paged(
-            qg, *args, cache["pos"], tbl, row_pos[:, None],
-            scale=scale, causal=spec.causal, window=window,
-            softcap=spec.logit_softcap,
-        )
+            pool = (cache["k"], None, cache["v"], None)
+
+        def kernel(rows, pool):
+            qg, tb, qp = rows
+            kq, ks, vq, vs, kpos = pool
+            return fused_decode_attention_paged(
+                qg, kq, ks, vq, vs, kpos, tb, qp,
+                scale=scale, causal=spec.causal, window=window,
+                softcap=spec.logit_softcap,
+            )
+
+        y = _over_slots(kernel, (qg, tbl, row_pos[:, None]), pool + (cache["pos"],))
         return y.reshape(B, 1, H, dh)
     if quant:
         k = materialize_kv(QuantizedKV(
-            _paged_gather(cache["k"].q, tbl), _paged_gather(cache["k"].scale, tbl),
+            gather_pages(cache["k"].q, tbl), gather_pages(cache["k"].scale, tbl),
             cache["k"].orig_dtype,
         ))
         v = materialize_kv(QuantizedKV(
-            _paged_gather(cache["v"].q, tbl), _paged_gather(cache["v"].scale, tbl),
+            gather_pages(cache["v"].q, tbl), gather_pages(cache["v"].scale, tbl),
             cache["v"].orig_dtype,
         ))
     else:
-        k = _paged_gather(cache["k"], tbl)
-        v = _paged_gather(cache["v"], tbl)
-    k_pos = _paged_gather(cache["pos"], tbl)
+        k = gather_pages(cache["k"], tbl)
+        v = gather_pages(cache["v"], tbl)
+    k_pos = gather_pages(cache["pos"], tbl)
     mask = _window_causal_mask(row_pos[:, None], k_pos, window, spec.causal)
     return _sdpa(q, k, v, mask, scale, spec.logit_softcap)
 
@@ -718,8 +695,10 @@ def attention(
                 y = _paged_prefill_chunk_attend_batched(q, k, v, cache, pos2d, block_table, spec, scale)
                 new_cache = _paged_cache_write_chunk_batched(cache, k, v, pos2d, block_table)
             else:
+                # one slot: the batched attend with a single table row
                 table_row = block_table[0] if block_table.ndim == 2 else block_table
-                y = _paged_prefill_chunk_attend(q, k, v, cache, pos2d, table_row, spec, scale)
+                y = _paged_prefill_chunk_attend_batched(
+                    q, k, v, cache, pos2d, table_row[None], spec, scale)
                 new_cache = _paged_cache_write_chunk(cache, k, v, pos2d[0], table_row)
         else:
             # window ring (or contiguous cache) resume: earlier chunks are in

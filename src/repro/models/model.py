@@ -79,7 +79,7 @@ def init_paged_caches(
     cross_len: int = 0, kv_bits: int = 0,
 ) -> dict:
     """Paged serving caches: global-context self-attention K/V live in shared
-    page pools ``[n_pages + 1, page_size, H_kv, dh]`` addressed through
+    page pools ``[n_pages + 1, H_kv, page_size, dh]`` addressed through
     per-slot block tables, instead of reserving ``capacity`` tokens per slot
     (serving/kv_pool.py).  Window rings, cross caches, and SSM/LRU states
     stay per-slot (``slots`` batch rows) — they are fixed-size already.
@@ -794,8 +794,8 @@ def paged_prefill_into_slot(
 
     def _scatter_self(pool, tmp):
         # pool: {"k","v","pos"} with leading repeats axis, pool tensors
-        # [R, Pt, ps, ...]; tmp: contiguous [R, 1, capacity, ...] with the
-        # prompt written at 0..S-1
+        # [R, Pt, Hkv, ps, ...] and pos [R, Pt, ps]; tmp: contiguous
+        # [R, 1, capacity, ...] with the prompt written at 0..S-1
         Pt, ps = pool["pos"].shape[1], pool["pos"].shape[2]
         pages = table_row[pos_vec // ps]
         pages = jnp.where((pages < 0) | (pos_vec < start), Pt - 1, pages).astype(jnp.int32)
@@ -804,16 +804,19 @@ def paged_prefill_into_slot(
         def scat(buf, vals):
             return buf.at[:, pages, offs].set(vals)
 
+        def scat_heads(buf, vals):  # vals [R, S, Hkv, ...] -> index order [S, R, Hkv, ...]
+            return buf.at[:, pages, :, offs].set(jnp.swapaxes(vals, 0, 1))
+
         def scat_kv(old, tmp_kv):
             if isinstance(old, QuantizedKV):
                 # tmp was quantized on write during prefill — copy (q, scale)
                 # pairs verbatim, no requantization
                 return QuantizedKV(
-                    scat(old.q, tmp_kv.q[:, 0, :S]),
-                    scat(old.scale, tmp_kv.scale[:, 0, :S]),
+                    scat_heads(old.q, tmp_kv.q[:, 0, :S]),
+                    scat_heads(old.scale, tmp_kv.scale[:, 0, :S]),
                     old.orig_dtype,
                 )
-            return scat(old, tmp_kv[:, 0, :S].astype(old.dtype))
+            return scat_heads(old, tmp_kv[:, 0, :S].astype(old.dtype))
 
         pos_val = jnp.where(pages == Pt - 1, -1, pos_vec)
         return {

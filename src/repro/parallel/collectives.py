@@ -22,7 +22,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.compat import axis_size
 
 
 def flat_all_to_all(x: jax.Array, axis_names) -> jax.Array:
@@ -41,8 +40,8 @@ def hierarchical_all_to_all(x: jax.Array, inner_axis: str, outer_axis: str) -> j
     Returns [E_loc, Go*Gi*C, D] — same result as flat_all_to_all over
     (outer, inner), via intra-inner exchange + layout transform + inter-outer
     exchange."""
-    Go = axis_size(outer_axis)
-    Gi = axis_size(inner_axis)
+    Go = jax.lax.axis_size(outer_axis)
+    Gi = jax.lax.axis_size(inner_axis)
     E, C, D = x.shape
     E_loc = E // (Go * Gi)
 
@@ -66,8 +65,8 @@ def hierarchical_all_to_all(x: jax.Array, inner_axis: str, outer_axis: str) -> j
 
 def hierarchical_all_to_all_back(y: jax.Array, inner_axis: str, outer_axis: str) -> jax.Array:
     """Inverse of hierarchical_all_to_all: [E_loc, Go*Gi*C, D] -> [E, C, D]."""
-    Go = axis_size(outer_axis)
-    Gi = axis_size(inner_axis)
+    Go = jax.lax.axis_size(outer_axis)
+    Gi = jax.lax.axis_size(inner_axis)
     E_loc, PC, D = y.shape
     C = PC // (Go * Gi)
     yv = y.reshape(1, E_loc, Go, Gi * C, D)

@@ -47,6 +47,17 @@ def use_mesh(mesh: Optional[Mesh], rules: Optional[dict] = None):
         _state.rules = prev_rules
 
 
+def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: GSPMD propagates shardings
+    and ``shard_hint`` constraints steer it, which is how every mesh in this
+    repo is used."""
+    n = len(tuple(axis_shapes))
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * n, devices=devices,
+    )
+
+
 def get_mesh() -> Optional[Mesh]:
     return getattr(_state, "mesh", None)
 

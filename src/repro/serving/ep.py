@@ -31,7 +31,7 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import NamedSharding
 
-from repro.parallel.compat import make_mesh
+from repro.parallel.sharding import make_mesh
 from repro.parallel.sharding import DEFAULT_RULES, use_mesh
 
 

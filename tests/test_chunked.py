@@ -70,8 +70,8 @@ def _toy_chunk(quantized, n_hist=6):
     key = jax.random.PRNGKey(0)
     C, Hkv, G, dh, ps, Pt = 5, 2, 2, 8, 4, 10
     q = jax.random.normal(key, (C, Hkv, G, dh), jnp.float32)
-    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, ps, Hkv, dh), jnp.float32)
-    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, ps, Hkv, dh), jnp.float32)
+    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, Hkv, ps, dh), jnp.float32)
+    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, Hkv, ps, dh), jnp.float32)
     ck = jax.random.normal(jax.random.fold_in(key, 3), (C, Hkv, dh), jnp.float32)
     cv = jax.random.normal(jax.random.fold_in(key, 4), (C, Hkv, dh), jnp.float32)
     kpos = np.full((Pt, ps), -1, np.int32)
@@ -90,6 +90,11 @@ def _toy_chunk(quantized, n_hist=6):
     return q, kq, ks, vq, vs, jnp.asarray(kpos), jnp.asarray(table), qpos, ck, cv
 
 
+def _rows(q, kq, ks, vq, vs, kpos, table, qpos, ck, cv):
+    """One slot's chunk as a one-row batch (the kernel's grid axis 0)."""
+    return q[None], kq, ks, vq, vs, kpos, table[None], qpos[None], ck[None], cv[None]
+
+
 class TestPrefillKernel:
     @pytest.mark.parametrize("quantized", [False, True])
     def test_kernel_matches_ref(self, quantized):
@@ -98,7 +103,7 @@ class TestPrefillKernel:
             paged_prefill_attention_ref,
         )
 
-        args = _toy_chunk(quantized)
+        args = _rows(*_toy_chunk(quantized))
         out_k = paged_prefill_attention(*args, scale=0.3, interpret=True)
         out_r = paged_prefill_attention_ref(*args, scale=0.3)
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=1e-5)
@@ -109,7 +114,7 @@ class TestPrefillKernel:
             paged_prefill_attention_ref,
         )
 
-        args = _toy_chunk(False)
+        args = _rows(*_toy_chunk(False))
         kw = dict(scale=0.3, causal=True, window=4, softcap=5.0)
         out_k = paged_prefill_attention(*args, interpret=True, **kw)
         out_r = paged_prefill_attention_ref(*args, **kw)
@@ -125,9 +130,9 @@ class TestPrefillKernel:
         )
 
         q, kq, ks, vq, vs, _, table, _, ck, cv = _toy_chunk(False)
-        kpos = jnp.full(kq.shape[:2], -1, jnp.int32)
+        kpos = jnp.full((kq.shape[0], kq.shape[2]), -1, jnp.int32)  # [Pt, ps]
         qpos = jnp.arange(q.shape[0], dtype=jnp.int32)
-        args = (q, kq, ks, vq, vs, kpos, table, qpos, ck, cv)
+        args = _rows(q, kq, ks, vq, vs, kpos, table, qpos, ck, cv)
         out_k = paged_prefill_attention(*args, scale=0.3, interpret=True)
         out_r = paged_prefill_attention_ref(*args, scale=0.3)
         assert np.isfinite(np.asarray(out_k)).all()
@@ -145,13 +150,36 @@ class TestPrefillKernel:
 
         q, kq, ks, vq, vs, kpos, table, _, ck, cv = _toy_chunk(False, n_hist=6)
         qpos = jnp.arange(2, 2 + q.shape[0], dtype=jnp.int32)  # overlaps hist 2..5
-        full = (q, kq, ks, vq, vs, kpos, table, qpos, ck, cv)
+        full = _rows(q, kq, ks, vq, vs, kpos, table, qpos, ck, cv)
         # oracle: the same pool with the overlapping entries truly emptied
         kpos_clean = jnp.where(kpos >= 2, -1, kpos)
-        clean = (q, kq, ks, vq, vs, kpos_clean, table, qpos, ck, cv)
+        clean = _rows(q, kq, ks, vq, vs, kpos_clean, table, qpos, ck, cv)
         out_k = paged_prefill_attention(*full, scale=0.3, interpret=True)
         out_r = paged_prefill_attention_ref(*clean, scale=0.3)
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=1e-5)
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    def test_rows_are_independent(self, quantized):
+        """One launch over several rows equals one launch per row: each row
+        reads only its own table and masks history at its own chunk start;
+        a row of all -1 positions (an inactive slot) stays finite."""
+        from repro.kernels.attention_prefill_paged import paged_prefill_attention
+
+        q, kq, ks, vq, vs, kpos, table, qpos, ck, cv = _toy_chunk(quantized)
+        C = q.shape[0]
+        tables = jnp.stack([table, jnp.asarray([7, 1, 3, kq.shape[0] - 1], jnp.int32), table])
+        qposs = jnp.stack([qpos, jnp.arange(2, 2 + C, dtype=jnp.int32),
+                           jnp.full((C,), -1, jnp.int32)])
+        qs = jnp.stack([q, q[::-1], q])
+        cks, cvs = jnp.stack([ck, cv, ck]), jnp.stack([cv, ck, cv])
+        both = paged_prefill_attention(qs, kq, ks, vq, vs, kpos, tables, qposs, cks, cvs,
+                                       scale=0.3, interpret=True)
+        assert np.isfinite(np.asarray(both)).all()
+        for b in range(2):
+            one = paged_prefill_attention(
+                qs[b:b + 1], kq, ks, vq, vs, kpos, tables[b:b + 1], qposs[b:b + 1],
+                cks[b:b + 1], cvs[b:b + 1], scale=0.3, interpret=True)
+            np.testing.assert_allclose(np.asarray(both[b]), np.asarray(one[0]), atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,7 @@ def run_script(body: str, n_dev: int = 8) -> str:
 PREAMBLE = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh
+from repro.parallel.sharding import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 """
 
@@ -96,7 +96,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collectives import (flat_all_to_all, flat_all_to_all_back,
     hierarchical_all_to_all, hierarchical_all_to_all_back)
-from repro.parallel.compat import make_mesh, shard_map
+from repro.parallel.sharding import make_mesh
+from jax import shard_map
 mesh = make_mesh((2, 4), ("pod", "data"))
 E, C, D = 16, 4, 8
 xg = jax.random.normal(jax.random.PRNGKey(0), (8, E, C, D))
@@ -125,7 +126,20 @@ from repro.parallel.sharding import use_mesh
 from repro.parallel.params import param_pspecs, batch_pspec
 from jax.sharding import NamedSharding
 
+import dataclasses
 cfg = make_reduced(all_configs()["llama4-maverick-400b-a17b"])
+# The EP path sizes capacity per shard (T/ep tokens) while the single-device
+# dense path sizes it over all T, so at the config's 1.25 they drop different
+# tokens.  capacity_factor = num_experts is drop-free on both sides: only the
+# arithmetic is compared.
+def _drop_free(ls):
+    if not getattr(ls.ffn, "num_experts", 0):
+        return ls
+    return dataclasses.replace(ls, ffn=dataclasses.replace(
+        ls.ffn, capacity_factor=float(ls.ffn.num_experts)))
+cfg = cfg.replace(segments=tuple(
+    dataclasses.replace(seg, pattern=tuple(_drop_free(ls) for ls in seg.pattern))
+    for seg in cfg.segments))
 params = init_params(cfg, jax.random.PRNGKey(0))
 opt = init_adamw(params)
 toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab_size)
@@ -208,7 +222,7 @@ class TestContextParallelAttention:
         run_script("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.parallel.compat import make_mesh
+from repro.parallel.sharding import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 from repro.configs.base import AttnSpec, ModelConfig
 from repro.models.attention import attention, init_attention
@@ -244,7 +258,7 @@ from repro.parallel.sharding import use_mesh, RULESETS
 
 cfg = ModelConfig(name="t", family="moe", source="x", d_model=64, num_heads=4, num_kv_heads=2,
                   head_dim=16, vocab_size=100, segments=(), param_dtype="float32", compute_dtype="float32")
-from repro.parallel.compat import make_mesh
+from repro.parallel.sharding import make_mesh
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 spec = FFNSpec(kind="moe", d_ff=128, num_experts=8, top_k=2, capacity_factor=8.0)
 p = init_moe(jax.random.PRNGKey(0), cfg, spec, jnp.float32)

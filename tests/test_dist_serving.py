@@ -216,7 +216,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.gating import top_k_gating
 from repro.core.dispatch import dispatch_dense
 from repro.parallel.collectives import flat_all_to_all, hierarchical_all_to_all
-from repro.parallel.compat import make_mesh, shard_map
+from repro.parallel.sharding import make_mesh
+from jax import shard_map
 
 SEED = %d
 rng = np.random.default_rng(SEED)
@@ -299,7 +300,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collectives import (flat_all_to_all, flat_all_to_all_back,
     hierarchical_all_to_all, hierarchical_all_to_all_back)
-from repro.parallel.compat import make_mesh, shard_map
+from repro.parallel.sharding import make_mesh
+from jax import shard_map
 
 rng = np.random.default_rng(%d)
 for shape in [(2, 4), (4, 2)]:

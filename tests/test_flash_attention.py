@@ -31,7 +31,7 @@ class TestFlashAttention:
     )
     def test_matches_ref(self, BH, S, T, dh, causal):
         q, k, v = _qkv(BH, S, T, dh, seed=S + T)
-        got = flash_attention(q, k, v, scale=dh**-0.5, causal=causal)
+        got = flash_attention(q, k, v, scale=dh**-0.5, causal=causal, interpret=True)
         want = flash_attention_ref(q, k, v, scale=dh**-0.5, causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
 
@@ -39,7 +39,7 @@ class TestFlashAttention:
         """Online softmax must be exact regardless of the k-tiling."""
         q, k, v = _qkv(1, 256, 512, 32, seed=9)
         outs = [
-            flash_attention(q, k, v, scale=0.2, causal=False, block_q=bq, block_k=bk)
+            flash_attention(q, k, v, scale=0.2, causal=False, block_q=bq, block_k=bk, interpret=True)
             for bq, bk in [(128, 512), (128, 128), (256, 64)]
         ]
         for o in outs[1:]:
@@ -47,7 +47,7 @@ class TestFlashAttention:
 
     def test_bf16(self):
         q, k, v = (t.astype(jnp.bfloat16) for t in _qkv(2, 128, 128, 64, seed=4))
-        got = flash_attention(q, k, v, scale=0.125, causal=True)
+        got = flash_attention(q, k, v, scale=0.125, causal=True, interpret=True)
         want = flash_attention_ref(q, k, v, scale=0.125, causal=True)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2
@@ -65,6 +65,6 @@ class TestFlashAttention:
         if causal:
             T = S  # kernel's causal mask assumes aligned q/k position ranges
         q, k, v = _qkv(1, S, T, dh, seed=seed)
-        got = flash_attention(q, k, v, scale=dh**-0.5, causal=causal)
+        got = flash_attention(q, k, v, scale=dh**-0.5, causal=causal, interpret=True)
         want = flash_attention_ref(q, k, v, scale=dh**-0.5, causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=1e-3)

@@ -131,7 +131,7 @@ class TestKernelVsRef:
 
     def test_fp_kernel_matches_ref(self):
         xg, te, wi, wg, wo = self._buffers()
-        got = grouped_mlp_kernel(xg, te, wi, wg, wo, interpret=True)
+        got = grouped_mlp_kernel(xg, te, wi, wg, wo, act="swiglu", interpret=True)
         want = grouped_mlp_ref(xg, te, wi, wg, wo)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
@@ -142,7 +142,7 @@ class TestKernelVsRef:
         qwi = QuantizedArray.quantize(wi, bits=bits, reduce_axes=(-2,))
         qwg = QuantizedArray.quantize(wg, bits=bits, reduce_axes=(-2,))
         qwo = QuantizedArray.quantize(wo, bits=bits, reduce_axes=(-2,))
-        got = grouped_mlp_quant(xg, te, qwi, qwg, qwo, interpret=True)
+        got = grouped_mlp_quant(xg, te, qwi, qwg, qwo, act="swiglu", interpret=True)
         want = grouped_mlp_quant_ref(xg, te, qwi, qwg, qwo)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-4)
@@ -156,7 +156,41 @@ class TestKernelVsRef:
         qwo = QuantizedArray.quantize(wo, bits=8, group_size=16,
                                       reduce_axes=(-2,))
         with pytest.raises(ValueError, match="per-output-channel"):
-            grouped_mlp_quant(xg, te, qwi, qwg, qwo, interpret=True)
+            grouped_mlp_quant(xg, te, qwi, qwg, qwo, act="swiglu", interpret=True)
+
+    @pytest.mark.parametrize("act", ["gelu", "relu"])
+    def test_fp_kernel_ungated_act_matches_ref(self, act):
+        """GELU / ReLU layers (the paper's NLG family is GELU) run in the
+        kernel with no gate projection streamed."""
+        xg, te, wi, _, wo = self._buffers()
+        got = grouped_mlp_kernel(xg, te, wi, None, wo, act=act, interpret=True)
+        want = grouped_mlp_ref(xg, te, wi, None, wo, act)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+    @pytest.mark.parametrize("bits", [8, 4])
+    def test_quant_kernel_gelu_matches_ref(self, bits):
+        xg, te, wi, _, wo = self._buffers()
+        qwi = QuantizedArray.quantize(wi, bits=bits, reduce_axes=(-2,))
+        qwo = QuantizedArray.quantize(wo, bits=bits, reduce_axes=(-2,))
+        got = grouped_mlp_quant(xg, te, qwi, None, qwo, act="gelu", interpret=True)
+        want = grouped_mlp_quant_ref(xg, te, qwi, None, qwo, "gelu")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+    def test_kernel_mode_raises_on_incompatible_layout(self):
+        """Under "kernel" the layer never reaches the gather-einsum ref in
+        silence: group-wise scales and a gate on a GELU layer both raise;
+        "ref" stays the explicit way to run the oracle."""
+        xg, te, wi, wg, wo = self._buffers()
+        grouped = [QuantizedArray.quantize(w, bits=8, group_size=16, reduce_axes=(-2,))
+                   for w in (wi, wg, wo)]
+        qp = dict(zip(("wi", "wg", "wo"), grouped))
+        with pytest.raises(ValueError, match="group_size"):
+            grouped_experts_ffn(qp, xg, te, "swiglu", backend="kernel")
+        with pytest.raises(ValueError, match="gate"):
+            grouped_experts_ffn({"wi": wi, "wg": wg, "wo": wo}, xg, te, "gelu",
+                                backend="kernel")
+        got = grouped_experts_ffn(qp, xg, te, "swiglu", backend="ref")
+        assert np.isfinite(np.asarray(got)).all()
 
 
 # ---------------------------------------------------------------------------

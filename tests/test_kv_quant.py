@@ -106,7 +106,7 @@ class TestDecodeKernel:
         kpos = kpos.at[:, 40:].set(-1)  # empty ring slots
         qpos = jnp.full((B, 1), 39, jnp.int32)
         args = dict(scale=0.25, window=window, softcap=softcap)
-        yk = decode_attention_quant(q, kq, ks, vq, vs, kpos, qpos, **args)
+        yk = decode_attention_quant(q, kq, ks, vq, vs, kpos, qpos, interpret=True, **args)
         yr = decode_attention_quant_ref(q, kq, ks, vq, vs, kpos, qpos, **args)
         np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), atol=1e-5)
 
@@ -118,7 +118,7 @@ class TestDecodeKernel:
         kq, ks = kv_quantize_values(k)
         kpos = jnp.arange(T, dtype=jnp.int32)[None]
         qpos = jnp.full((B, 1), T - 1, jnp.int32)
-        yk = decode_attention_quant(q, kq, ks, kq, ks, kpos, qpos, scale=0.25, block_t=16)
+        yk = decode_attention_quant(q, kq, ks, kq, ks, kpos, qpos, scale=0.25, block_t=16, interpret=True)
         yr = decode_attention_quant_ref(q, kq, ks, kq, ks, kpos, qpos, scale=0.25)
         np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), atol=1e-5)
 

@@ -106,8 +106,8 @@ def _toy_pool(quantized):
     key = jax.random.PRNGKey(0)
     B, Hkv, G, dh, ps, nt, Pt = 3, 2, 2, 8, 4, 5, 12  # Pt-1 = trash page
     q = jax.random.normal(key, (B, Hkv, G, dh), jnp.float32)
-    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, ps, Hkv, dh), jnp.float32)
-    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, ps, Hkv, dh), jnp.float32)
+    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, Hkv, ps, dh), jnp.float32)
+    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, Hkv, ps, dh), jnp.float32)
     kpos = np.full((Pt, ps), -1, np.int32)
     tables = np.full((B, nt), -1, np.int32)
     seqs = {0: ([3, 7, 0], 10), 1: ([5, 9], 6), 2: ([1], 2)}

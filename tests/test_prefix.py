@@ -117,7 +117,7 @@ class TestPhysicalSharing:
         assert int(eng.tables.row(1)[2]) == boundary
         assert eng.pool.refcount(boundary) == 2
         pool0 = _first_paged_self(cfg, eng.caches)
-        before_k = np.asarray(pool0["k"][:, boundary, :2])  # prompt entries
+        before_k = np.asarray(pool0["k"][:, boundary, :, :2])  # prompt entries (all heads)
         before_pos = np.asarray(pool0["pos"][:, boundary, :2])
         eng.step()
         assert eng.cow_copies >= 1
@@ -126,7 +126,7 @@ class TestPhysicalSharing:
         pool1 = _first_paged_self(cfg, eng.caches)
         for b in (int(eng.tables.row(0)[2]), int(eng.tables.row(1)[2])):
             np.testing.assert_array_equal(np.asarray(pool1["pos"][:, b, :2]), before_pos)
-            np.testing.assert_array_equal(np.asarray(pool1["k"][:, b, :2]), before_k)
+            np.testing.assert_array_equal(np.asarray(pool1["k"][:, b, :, :2]), before_k)
         done = eng.run_until_done()
         assert len(done) == 2
         assert eng.pool.free_count == eng.n_pages

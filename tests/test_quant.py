@@ -168,8 +168,10 @@ class TestQuantKernel:
         assert float(jnp.abs(got - fp).max()) < 0.05 * max(scale, 1.0)
 
     def test_kernel_mode_falls_back_on_nondivisible_shapes(self):
-        """expert_capacity pads to 8, not 128 — forced-kernel routing must
-        fall back to the einsum ref for C not divisible by the block."""
+        """expert_capacity pads to 8, not 128 — C not divisible by the block
+        is a layout the kernel cannot take.  "kernel" mode raises rather
+        than widening the experts through the einsum ref behind the
+        caller's back; the fallback is the explicit "ref" backend."""
         from repro.core.moe import experts_ffn
         from repro.kernels.expert_mlp_quant import _check_kernel_compat
 
@@ -182,7 +184,9 @@ class TestQuantKernel:
         qp = {"wi": QuantizedArray.quantize(wi), "wg": QuantizedArray.quantize(wg),
               "wo": QuantizedArray.quantize(wo)}
         assert not _check_kernel_compat(xe, qp["wi"], qp["wg"], qp["wo"])
-        got = experts_ffn(qp, xe, "swiglu", backend="kernel")  # must not crash
+        with pytest.raises(ValueError, match="block-divisible"):
+            experts_ffn(qp, xe, "swiglu", backend="kernel")
+        got = experts_ffn(qp, xe, "swiglu", backend="ref")
         want = expert_mlp_quant_ref(xe, qp["wi"], qp["wg"], qp["wo"])
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
