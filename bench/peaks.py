@@ -1,0 +1,20 @@
+"""Published peaks of each chip, keyed by the ``device_kind`` JAX reports.
+A kind that is not here is an error, never a default.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture): per
+chip 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s, and
+1,600 Gbit/s of chip-to-chip interconnect.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flop_s": 197e12, "int8_op_s": 393e12, "hbm_bytes": 16e9,
+                    "hbm_bytes_s": 819e9, "ici_bits_s": 1.6e12},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
