@@ -11,8 +11,9 @@ inside jitted code).  This pass finds the syntactic shapes those bugs take:
                         layers, a jitted ``self._*`` engine function, or a
                         local previously bound to one).  Blocks until the
                         value is ready.
-  * ``host-asarray``  — ``np.asarray()/np.array()`` over a device value:
-                        the transfer that ends XLA's async dispatch pipeline.
+  * ``host-asarray``  — ``np.asarray()/np.array()/jax.device_get()`` over a
+                        device value: the transfer that ends XLA's async
+                        dispatch pipeline.
   * ``tracer-branch`` — Python ``if``/``while``/``assert`` on a device value
                         inside *traced* modules (models/core/kernels/quant):
                         under ``jit`` this is a ConcretizationTypeError at
@@ -57,6 +58,9 @@ PRAGMA_RE = re.compile(r"#\s*analysis:\s*allow\(\s*([\w\-*,\s]+?)\s*\)")
 
 RULES = ("host-item", "host-cast", "host-asarray", "tracer-branch",
          "debug-call", "block-sync")
+
+# calls that copy device values to the host (blocking) and return host values
+_TRANSFERS = {"np.asarray", "np.array", "numpy.asarray", "numpy.array", "jax.device_get"}
 
 # attribute reads that are static metadata, not device values
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "sharding", "aval"}
@@ -212,8 +216,8 @@ class _FileLinter(ast.NodeVisitor):
             if not isinstance(t, (ast.Name, ast.Tuple, ast.List, ast.Starred)):
                 self.visit(t)
         root = _dotted(node.value.func) if isinstance(node.value, ast.Call) else None
-        if root and root.split(".")[0] == "np":
-            pass  # np.* results are host values — the sync already happened
+        if root and (root.split(".")[0] == "np" or root in _TRANSFERS):
+            pass  # np.* / device_get results are host values — the sync already happened
         elif self._is_device(node.value):
             self._bind_targets(node.targets)
         elif self.scopes:
@@ -247,8 +251,7 @@ class _FileLinter(ast.NodeVisitor):
                     and self._is_device(node.args[0]):
                 self._emit("host-cast", node,
                            f"`{name}()` over a device value blocks on the result")
-            elif name in ("np.asarray", "np.array", "numpy.asarray", "numpy.array") \
-                    and node.args and self._is_device(node.args[0]):
+            elif name in _TRANSFERS and node.args and self._is_device(node.args[0]):
                 self._emit("host-asarray", node,
                            f"`{name}` of a device value is a blocking transfer")
             elif name.startswith("jax.debug."):

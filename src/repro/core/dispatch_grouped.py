@@ -111,12 +111,15 @@ def moe_grouped(x: jax.Array, g: Gating, num_experts: int,
     T, D = x.shape
     K = g.expert_idx.shape[1]
     TK = T * K
-    layout = grouped_layout(g, num_experts, tile=tile)
-    token = jnp.arange(TK, dtype=jnp.int32) // K  # flat slot -> source token
-    Ct = layout.tile_expert.shape[0] * tile
-    xg = jnp.zeros((Ct, D), x.dtype).at[layout.dst].set(x[token])
-    yg = expert_fn(xg, layout.tile_expert)  # [Ct, D]
-    w = g.combine_w.reshape(-1).astype(jnp.float32)  # keep is all-True (dropless)
-    y = jnp.zeros((T, D), jnp.float32).at[token].add(
-        w[:, None] * yg[layout.dst].astype(jnp.float32))
-    return y.astype(x.dtype)
+    with jax.named_scope("moe_dispatch"):
+        layout = grouped_layout(g, num_experts, tile=tile)
+        token = jnp.arange(TK, dtype=jnp.int32) // K  # flat slot -> source token
+        Ct = layout.tile_expert.shape[0] * tile
+        xg = jnp.zeros((Ct, D), x.dtype).at[layout.dst].set(x[token])
+    with jax.named_scope("expert_mlp"):
+        yg = expert_fn(xg, layout.tile_expert)  # [Ct, D]
+    with jax.named_scope("moe_combine"):
+        w = g.combine_w.reshape(-1).astype(jnp.float32)  # keep is all-True (dropless)
+        y = jnp.zeros((T, D), jnp.float32).at[token].add(
+            w[:, None] * yg[layout.dst].astype(jnp.float32))
+        return y.astype(x.dtype)

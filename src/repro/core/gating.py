@@ -132,16 +132,20 @@ class RoutingStats(NamedTuple):
     p: jax.Array
 
 
+def kept_counts(g: Gating, num_experts: int) -> jax.Array:
+    """[E] int32: kept assignments per expert over ALL k slots (dropped ones
+    route to a scratch bucket at index E and are cut off)."""
+    kept_idx = jnp.where(g.keep, g.expert_idx, num_experts).reshape(-1)
+    return jnp.bincount(kept_idx, length=num_experts + 1)[:num_experts].astype(jnp.int32)
+
+
 def routing_stats(g: Gating, num_experts: int) -> RoutingStats:
     """RoutingStats from one layer's gating decision.  ``f``/``p`` (and the
     ``imbalance`` built from them) are exactly ``load_balance_stats`` — the
     parity tests/test_obs.py asserts — so telemetry can never drift from the
     loss the model trains against."""
     f, p = load_balance_stats(g.probs, g.expert_idx, num_experts)
-    # kept assignments per expert over ALL k slots (dropped ones route to a
-    # scratch bucket at index E and are cut off)
-    kept_idx = jnp.where(g.keep, g.expert_idx, num_experts).reshape(-1)
-    tokens_per_expert = jnp.bincount(kept_idx, length=num_experts + 1)[:num_experts]
+    tokens_per_expert = kept_counts(g, num_experts)
     dropped = 1.0 - jnp.mean(g.keep.astype(jnp.float32))
     entropy = -jnp.mean(jnp.sum(g.probs * jnp.log(g.probs + 1e-9), axis=-1))
     imbalance = num_experts * jnp.sum(f * p)

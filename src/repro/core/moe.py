@@ -26,6 +26,7 @@ from repro.configs.base import FFNSpec, ModelConfig
 from repro.core import dispatch, dispatch_einsum, dispatch_grouped
 from repro.core.gating import (
     expert_capacity,
+    kept_counts,
     load_balance_loss,
     routing_stats,
     top_k_gating,
@@ -196,10 +197,15 @@ def moe_layer(
     *,
     impl: str | None = None,
     with_stats: bool = False,
+    with_routed: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """Returns (y [B,S,D], aux_loss scalar); with ``with_stats=True`` a
-    third element — a jit-returnable ``RoutingStats`` (token-count-
-    independent shapes) for per-layer telemetry (docs/OBSERVABILITY.md)."""
+    further element — a jit-returnable ``RoutingStats`` (token-count-
+    independent shapes) for per-layer telemetry (docs/OBSERVABILITY.md);
+    with ``with_routed=True`` a last element — an int32 scalar, the number
+    of experts the dispatch gave at least one row (its kept rows per
+    expert; on the EP paths, as the shard_map's dispatch counted them),
+    which is how many experts' weights the layer reads."""
     impl = impl or cfg.moe_impl
     B, S, D = x.shape
     E, K = spec.num_experts, spec.top_k
@@ -232,7 +238,7 @@ def moe_layer(
                 {k: params[k] for k in ("wi", "wg", "wo") if k in params}
             )}
         kernel = "grouped" if impl == "ep_grouped" else "dense"
-        y, aux = moe_layer_ep_serve(cfg, spec, params, x, kernel=kernel)
+        y, aux, counts = moe_layer_ep_serve(cfg, spec, params, x, kernel=kernel)
         if with_stats:
             # Router + gating re-run on the replicated token set outside
             # shard_map.  For the replicated-token schedules (decode,
@@ -261,7 +267,7 @@ def moe_layer(
             params = {**params, **dequantize_params(
                 {k: params[k] for k in ("wi", "wg", "wo") if k in params}
             )}
-        y, aux = moe_layer_ep(cfg, spec, params, x)
+        y, aux, counts = moe_layer_ep(cfg, spec, params, x)
         if with_stats:
             # Telemetry for the EP path: re-run router + gating on the full
             # (replicated) token set OUTSIDE shard_map.  probs/top-k/f/P are
@@ -276,23 +282,29 @@ def moe_layer(
     else:
         xs = x.reshape(B * S, D)
         T = B * S
-        logits = xs.astype(jnp.float32) @ params["router"]
-        if impl == "grouped":
-            # Dropless: gate with capacity = T*K, so every assignment keeps
-            # its expert by pigeonhole (keep all-True, f/P in RoutingStats
-            # still report the balance the aux loss shapes).
-            g = top_k_gating(logits, K, T * K)
+        grouped = impl == "grouped"
+        # Dropless (grouped): gate with capacity = T*K, so every assignment
+        # keeps its expert by pigeonhole (keep all-True, f/P in
+        # RoutingStats still report the balance the aux loss shapes).
+        capacity = T * K if grouped else expert_capacity(T, E, K, spec.capacity_factor)
+        with jax.named_scope("moe_gate"):
+            logits = xs.astype(jnp.float32) @ params["router"]
+            g = top_k_gating(logits, K, capacity)
+        if grouped:
             y = dispatch_grouped.moe_grouped(
                 xs, g, E, lambda xg, te: grouped_experts_ffn(params, xg, te, spec.act)
             )
         else:
-            capacity = expert_capacity(T, E, K, spec.capacity_factor)
-            g = top_k_gating(logits, K, capacity)
-            ef = lambda xe: experts_ffn(params, xe, spec.act)
-            if impl == "einsum":
-                y = dispatch_einsum.moe_einsum(xs, g, capacity, ef)
-            else:  # dense mapping-table
-                y = dispatch.moe_dense(xs, g, capacity, E, ef)
+            def ef(xe):
+                with jax.named_scope("expert_mlp"):
+                    return experts_ffn(params, xe, spec.act)
+
+            with jax.named_scope("moe_dispatch"):
+                if impl == "einsum":
+                    y = dispatch_einsum.moe_einsum(xs, g, capacity, ef)
+                else:  # dense mapping-table
+                    y = dispatch.moe_dense(xs, g, capacity, E, ef)
+        counts = kept_counts(g, E)  # grouped: keep is all-True, the layout's counts
         aux = load_balance_loss(g.probs, g.expert_idx, E)
         if with_stats:
             stats = routing_stats(g, E)
@@ -300,8 +312,12 @@ def moe_layer(
 
     if spec.residual:
         # Residual-MoE (§4.1.1): fixed dense MLP branch + gated expert branch.
-        y = y + mlp(params["residual"], x, spec.act)
+        with jax.named_scope("residual_mlp"):
+            y = y + mlp(params["residual"], x, spec.act)
     y = shard_hint(y, "batch", "seq", "embed")
+    out = (y, aux)
     if with_stats:
-        return y, aux, stats
-    return y, aux
+        out += (stats,)
+    if with_routed:
+        out += (jnp.sum(counts > 0).astype(jnp.int32),)
+    return out

@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import FFNSpec, ModelConfig
 from repro.core.dispatch import combine_dense, dispatch_dense
-from repro.core.gating import expert_capacity, load_balance_loss, top_k_gating
+from repro.core.gating import expert_capacity, kept_counts, load_balance_loss, top_k_gating
 from repro.parallel.sharding import get_mesh
 
 EP_AXIS = "data"
@@ -138,7 +138,9 @@ def _moe_body(cfg: ModelConfig, spec: FFNSpec, mesh, hier: bool, x_loc, router, 
     f = jax.lax.pmean(f, tuple(axes))
     p = jax.lax.pmean(p, tuple(axes))
     aux = E * jnp.sum(f * p)
-    return y, aux
+    # rows each expert receives: every shard's kept assignments, summed
+    counts = jax.lax.psum(kept_counts(g, E), tuple(axes))
+    return y, aux, counts
 
 
 def _moe_body_allgather(cfg: ModelConfig, spec: FFNSpec, mesh, x_loc, router, wi, wg, wo):
@@ -193,10 +195,19 @@ def _moe_body_allgather(cfg: ModelConfig, spec: FFNSpec, mesh, x_loc, router, wi
     # token set); the pmean just certifies replication for shard_map's vma.
     axes = [EP_AXIS] + (["pod"] if _axis_in_mesh(mesh, "pod") else [])
     aux = jax.lax.pmean(aux, tuple(axes))
-    return y.reshape(B_loc, S, D), aux
+    # rows each expert receives: this shard's experts' kept rows, placed at
+    # their global ids and summed over the EP shards (and the pods' tokens)
+    counts = jax.lax.dynamic_update_slice(
+        jnp.zeros((E,), jnp.int32), kept_counts(g_local, E_loc), (lo,))
+    counts = jax.lax.psum(counts, tuple(axes))
+    return y.reshape(B_loc, S, D), aux, counts
 
 
-def moe_layer_ep(cfg: ModelConfig, spec: FFNSpec, params: dict, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def moe_layer_ep(cfg: ModelConfig, spec: FFNSpec, params: dict,
+                 x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(y, aux, counts)``: ``counts`` [E] int32 is the rows the dispatch
+    gave each expert over the whole batch (read by the routed-expert
+    counter; unused, it is dropped with its psum at tracing)."""
     mesh = get_mesh()
     assert mesh is not None, "moe_impl='ep' requires an active mesh (parallel.sharding.use_mesh)"
     has_pod = _axis_in_mesh(mesh, "pod")
@@ -234,7 +245,7 @@ def moe_layer_ep(cfg: ModelConfig, spec: FFNSpec, params: dict, x: jax.Array) ->
         body,
         mesh=mesh,
         in_specs=(x_spec, router_spec, wi_spec, wi_spec, wo_spec),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P(), P()),
         check_vma=True,
     )
     # Pin every operand to its in_spec with an explicit constraint before the

@@ -44,7 +44,13 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import FFNSpec, ModelConfig
 from repro.core.dispatch import combine_dense, dispatch_dense
 from repro.core.dispatch_grouped import GROUPED_TILE, grouped_layout
-from repro.core.gating import expert_capacity, load_balance_loss, load_balance_stats, top_k_gating
+from repro.core.gating import (
+    expert_capacity,
+    kept_counts,
+    load_balance_loss,
+    load_balance_stats,
+    top_k_gating,
+)
 from repro.parallel.sharding import get_mesh, get_rules
 
 
@@ -132,7 +138,7 @@ def _body_replicated_dense(cfg: ModelConfig, spec: FFNSpec, axes, x, router, wi,
 
     aux = load_balance_loss(g.probs, g.expert_idx, E)
     aux = jax.lax.pmean(aux, axes)  # identical per shard; certifies replication
-    return y, aux
+    return y, aux, kept_counts(g, E)  # global gating: the same on every shard
 
 
 def _body_replicated_grouped(cfg: ModelConfig, spec: FFNSpec, axes, x, router, wi, wg, wo):
@@ -181,7 +187,7 @@ def _body_replicated_grouped(cfg: ModelConfig, spec: FFNSpec, axes, x, router, w
 
     aux = load_balance_loss(g.probs, g.expert_idx, E)
     aux = jax.lax.pmean(aux, axes)
-    return y, aux
+    return y, aux, layout.counts  # global layout: the same on every shard
 
 
 def _body_a2a(cfg: ModelConfig, spec: FFNSpec, axes, x_loc, router, wi, wg, wo):
@@ -227,7 +233,9 @@ def _body_a2a(cfg: ModelConfig, spec: FFNSpec, axes, x_loc, router, wi, wg, wo):
     f = jax.lax.pmean(f, axes)
     p = jax.lax.pmean(p, axes)
     aux = E * jnp.sum(f * p)
-    return y, aux
+    # rows each expert receives: every shard's kept assignments, summed
+    counts = jax.lax.psum(kept_counts(g, E), axes)
+    return y, aux, counts
 
 
 def moe_layer_ep_serve(
@@ -237,9 +245,12 @@ def moe_layer_ep_serve(
     x: jax.Array,  # [B, S, D]
     *,
     kernel: str = "dense",  # "dense" | "grouped"
-) -> Tuple[jax.Array, jax.Array]:
-    """Serving EP layer.  Caller (core/moe.py) guarantees an active mesh
-    whose 'expert' rule axes divide ``spec.num_experts`` (serve_ep_axes)."""
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Serving EP layer: ``(y, aux, counts)``, ``counts`` [E] int32 the rows
+    the dispatch gave each expert over the whole token set (read by the
+    routed-expert counter; unused, it is dropped with its psum at tracing).
+    Caller (core/moe.py) guarantees an active mesh whose 'expert' rule axes
+    divide ``spec.num_experts`` (serve_ep_axes)."""
     mesh = get_mesh()
     axes = serve_ep_axes(spec.num_experts)
     assert mesh is not None and axes is not None, "moe_layer_ep_serve requires a usable EP mesh"
@@ -277,7 +288,7 @@ def moe_layer_ep_serve(
             partial(body, cfg, spec, axes),
             mesh=mesh,
             in_specs=(rep, P(None, None), w_spec, w_spec, w_spec),
-            out_specs=(rep, rep),
+            out_specs=(rep, rep, rep),
             check_vma=False,
         )
         return fn(constrain(x, rep), *operands)
@@ -294,8 +305,8 @@ def moe_layer_ep_serve(
         partial(_body_a2a, cfg, spec, axes),
         mesh=mesh,
         in_specs=(tok_spec, rep, w_spec, w_spec, w_spec),
-        out_specs=(tok_spec, rep),
+        out_specs=(tok_spec, rep, rep),
         check_vma=False,
     )
-    y, aux = fn(constrain(xs, tok_spec), *operands)
-    return y[:T].reshape(B, S, D), aux
+    y, aux, counts = fn(constrain(xs, tok_spec), *operands)
+    return y[:T].reshape(B, S, D), aux, counts

@@ -202,6 +202,7 @@ def paged_decode_attention(
     )
     return pl.pallas_call(
         kern,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
         interpret=interpret,

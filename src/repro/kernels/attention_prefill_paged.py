@@ -199,6 +199,7 @@ def paged_prefill_attention(
     )
     out = pl.pallas_call(
         kern,
+        name="paged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, CG, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),

@@ -119,6 +119,7 @@ def decode_attention_quant(
     )
     return pl.pallas_call(
         kern,
+        name="decode_attention_quant",
         grid=(B, Hkv, nk),
         in_specs=[
             pl.BlockSpec((1, 1, G, dh), lambda b, h, t: (b, h, 0, 0)),

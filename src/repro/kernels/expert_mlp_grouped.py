@@ -116,6 +116,7 @@ def grouped_mlp_kernel(
     ws = (wi, wg, wo) if wg is not None else (wi, wo)
     out = pl.pallas_call(
         functools.partial(_grouped_mlp_kernel, act=act),
+        name="grouped_mlp_kernel",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Ct, D), jnp.float32),
         interpret=interpret,
@@ -213,6 +214,7 @@ def grouped_mlp_quant_kernel(
     gate = (wg_q, wg_s) if wg_q is not None else ()
     out = pl.pallas_call(
         functools.partial(_grouped_mlp_quant_kernel, bits=bits, act=act),
+        name="grouped_mlp_quant_kernel",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Ct, D), jnp.float32),
         interpret=interpret,

@@ -65,6 +65,7 @@ def expert_mlp_quant_kernel(
 
     out = pl.pallas_call(
         _expert_mlp_quant_kernel,
+        name="expert_mlp_quant_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bc, D), lambda e, c, f: (e, c, 0)),

@@ -87,6 +87,7 @@ def flash_attention(
     kern = functools.partial(_flash_kernel, scale=scale, causal=causal, nk=nk)
     return pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda bh, iq, ik: (bh, iq, 0)),

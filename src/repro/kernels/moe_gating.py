@@ -91,6 +91,7 @@ def gating_kernel(
     kern = functools.partial(_gating_kernel, top_k=top_k, E=E)
     eidx, w, pos, probs = pl.pallas_call(
         kern,
+        name="gating_kernel",
         grid=(nb,),
         in_specs=[pl.BlockSpec((bt, E), lambda t: (t, 0))],
         out_specs=(

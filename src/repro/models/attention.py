@@ -693,7 +693,8 @@ def attention(
             if batched:
                 # block_table is [B, max_pages] — one row per mid-prefill slot
                 y = _paged_prefill_chunk_attend_batched(q, k, v, cache, pos2d, block_table, spec, scale)
-                new_cache = _paged_cache_write_chunk_batched(cache, k, v, pos2d, block_table)
+                with jax.named_scope("kv_write"):
+                    new_cache = _paged_cache_write_chunk_batched(cache, k, v, pos2d, block_table)
             else:
                 # one slot: the batched attend with a single table row
                 table_row = block_table[0] if block_table.ndim == 2 else block_table
@@ -730,7 +731,8 @@ def attention(
         row_pos = jnp.broadcast_to(row_pos, (B,)).astype(jnp.int32)
         if mode == "decode_paged" and spec_is_paged(spec):
             assert block_table is not None, "decode_paged needs a block table"
-            new_cache = _paged_cache_write_decode(cache, k, v, row_pos, block_table)
+            with jax.named_scope("kv_write"):
+                new_cache = _paged_cache_write_decode(cache, k, v, row_pos, block_table)
             y = _paged_decode_attend(q, new_cache, row_pos, block_table, spec, scale)
         else:
             idx = row_pos if mode in ("decode_ragged", "decode_paged") else row_pos[0]

@@ -107,9 +107,10 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array
 
 
 def logits_out(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
-    x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
-    w = params["embed"].T if cfg.tie_embeddings else materialize(params["unembed"])
-    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
+    with jax.named_scope("logits"):
+        x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
+        w = params["embed"].T if cfg.tie_embeddings else materialize(params["unembed"])
+        logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
     return shard_hint(logits, "batch", "seq", "vocab")
 
 
@@ -138,33 +139,43 @@ def encode(cfg: ModelConfig, params: dict, source: jax.Array) -> jax.Array:
 
 
 def _run_segments(cfg, params, x, positions, caches, mode, memory, remat,
-                  block_table=None, collect_stats=False):
-    """With ``collect_stats=True`` returns a 4th element: ``{seg{i}: {pos{j}:
-    RoutingStats[repeats, ...]}}`` for every MoE position — the per-layer
-    routing telemetry tree (jit-returnable; host side aggregates via
-    ``core.gating.summarize_routing``)."""
+                  block_table=None, collect_stats=False, count_routed=False):
+    """With ``collect_stats=True`` returns a further element: ``{seg{i}:
+    {pos{j}: RoutingStats[repeats, ...]}}`` for every MoE position — the
+    per-layer routing telemetry tree (jit-returnable; host side aggregates
+    via ``core.gating.summarize_routing``).  With ``count_routed=True`` a
+    last element: int32 ``[n_moe_layers]``, in layer order, the number of
+    experts each MoE layer's dispatch gave at least one row."""
     aux = jnp.zeros((), jnp.float32)
     new_caches = {}
     stats = {}
+    routed = []
     for i, seg in enumerate(cfg.segments):
         c = caches.get(f"seg{i}") if caches is not None else None
         out = apply_segment(
             cfg, seg, params["segments"][f"seg{i}"], x, positions,
             caches=c, mode=mode, memory=memory, remat=remat, block_table=block_table,
-            collect_stats=collect_stats,
+            collect_stats=collect_stats, count_routed=count_routed,
         )
-        if collect_stats:
-            x, c_new, a, seg_stats = out
-            # analysis: allow(tracer-branch) — dict-emptiness check on a stats pytree (structure is static under tracing)
-            if seg_stats:
-                stats[f"seg{i}"] = seg_stats
+        if collect_stats or count_routed:
+            x, c_new, a, tel = out
+            moe = [f"pos{j}" for j, ls in enumerate(seg.pattern) if ls.ffn.kind == "moe"]
+            if collect_stats and moe:
+                stats[f"seg{i}"] = {pk: tel[pk]["stats"] for pk in moe}
+            if count_routed and moe:
+                # [repeats, positions] -> layer order (repeat-major)
+                routed.append(jnp.stack([tel[pk]["routed"] for pk in moe], axis=1).reshape(-1))
         else:
             x, c_new, a = out
         aux = aux + a
         if caches is not None:
             new_caches[f"seg{i}"] = c_new
     res = (x, (new_caches if caches is not None else None), aux)
-    return res + (stats,) if collect_stats else res
+    if collect_stats:
+        res += (stats,)
+    if count_routed:
+        res += (jnp.concatenate(routed) if routed else jnp.zeros((0,), jnp.int32),)
+    return res
 
 
 def forward(
@@ -253,24 +264,22 @@ def ragged_decode_step(
     *,
     memory: Optional[jax.Array] = None,
     return_routing: bool = False,
+    return_routed: bool = False,
 ) -> Tuple:
     """Continuous-batching decode tick: each slot/row decodes at its own
     position; inactive rows' caches are left untouched (masked merge).
     ``return_routing=True`` appends the routing-stats tree (stats cover
     every slot row, active or not — padding rows route too; host side
-    treats the per-tick stats as a load-shape sample, not exact counts)."""
+    treats the per-tick stats as a load-shape sample, not exact counts);
+    ``return_routed=True`` appends the per-MoE-layer routed-expert counts
+    (``_run_segments``), which count padding rows' experts too: the step
+    reads those experts' weights."""
     x = embed_tokens(cfg, params, token)
     pos2d = positions.astype(jnp.int32)[:, None]
-    routing = None
-    if return_routing:
-        x, new_caches, _, routing = _run_segments(
-            cfg, params, x, pos2d, caches, "decode_ragged", memory, False,
-            collect_stats=True,
-        )
-    else:
-        x, new_caches, _ = _run_segments(
-            cfg, params, x, pos2d, caches, "decode_ragged", memory, False
-        )
+    x, new_caches, _, *tel = _run_segments(
+        cfg, params, x, pos2d, caches, "decode_ragged", memory, False,
+        collect_stats=return_routing, count_routed=return_routed,
+    )
     logits = logits_out(cfg, params, x)[:, 0]
 
     def _merge(new, old):
@@ -279,9 +288,7 @@ def ragged_decode_step(
         return jnp.where(mask, new, old)
 
     merged = jax.tree.map(_merge, new_caches, caches)
-    if return_routing:
-        return logits, merged, routing
-    return logits, merged
+    return (logits, merged, *tel)
 
 
 def prefill_into_slot(
@@ -348,25 +355,20 @@ def paged_ragged_decode_step(
     *,
     memory: Optional[jax.Array] = None,
     return_routing: bool = False,
+    return_routed: bool = False,
 ) -> Tuple:
     """Continuous-batching decode tick over paged caches.  Pool writes are
     self-masking (inactive slots' table rows are all -1, so their writes land
     in the trash page); the per-slot leaves (window rings, SSM/LRU states,
     cross caches) get the same masked merge as ``ragged_decode_step``.
-    ``return_routing=True`` appends the routing-stats tree."""
+    ``return_routing=True`` appends the routing-stats tree and
+    ``return_routed=True`` the routed-expert counts, as there."""
     x = embed_tokens(cfg, params, token)
     pos2d = positions.astype(jnp.int32)[:, None]
-    routing = None
-    if return_routing:
-        x, new_caches, _, routing = _run_segments(
-            cfg, params, x, pos2d, caches, "decode_paged", memory, False,
-            block_table=block_table, collect_stats=True,
-        )
-    else:
-        x, new_caches, _ = _run_segments(
-            cfg, params, x, pos2d, caches, "decode_paged", memory, False,
-            block_table=block_table,
-        )
+    x, new_caches, _, *tel = _run_segments(
+        cfg, params, x, pos2d, caches, "decode_paged", memory, False,
+        block_table=block_table, collect_stats=return_routing, count_routed=return_routed,
+    )
     logits = logits_out(cfg, params, x)[:, 0]
 
     def _merge(new, old):
@@ -384,9 +386,7 @@ def paged_ragged_decode_step(
             else:
                 out[key] = jax.tree.map(_merge, c_new[key], c_old[key])
         merged.setdefault(sk, {})[pk] = out
-    if return_routing:
-        return logits, merged, routing
-    return logits, merged
+    return (logits, merged, *tel)
 
 
 def paged_reset_pages(cfg: ModelConfig, caches: dict, page_mask: jax.Array) -> dict:

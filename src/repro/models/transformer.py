@@ -100,21 +100,25 @@ def apply_layer(
     memory: Optional[jax.Array] = None,
     block_table: Optional[jax.Array] = None,
     collect_stats: bool = False,
+    count_routed: bool = False,
 ) -> Tuple:
-    """Returns (x, new_cache, aux_loss); with ``collect_stats=True`` a 4th
-    element — the MoE layer's jit-returnable ``RoutingStats`` (None for
-    non-MoE layers).  The flag is static (part of the trace), so telemetry
-    collection is decided when the caller builds its jitted step."""
+    """Returns (x, new_cache, aux_loss); with ``collect_stats`` or
+    ``count_routed`` a 4th element — the MoE layer's telemetry dict (None
+    for non-MoE layers): ``"stats"``, its jit-returnable ``RoutingStats``,
+    and ``"routed"``, the int32 count of experts its dispatch gave rows.
+    The flags are static (part of the trace), so telemetry collection is
+    decided when the caller builds its jitted step."""
     aux = jnp.zeros((), jnp.float32)
-    stats = None
+    tel = None
     h = rmsnorm(params["norm_mixer"], x, cfg.rms_eps)
     sc = cache.get("self") if cache is not None else None
     m = ls.mixer
     if isinstance(m, AttnSpec):
-        y, new_self = attention(
-            cfg, m, params["attn"], h, positions, cache=sc, mode=mode,
-            block_table=block_table,
-        )
+        with jax.named_scope("attn"):
+            y, new_self = attention(
+                cfg, m, params["attn"], h, positions, cache=sc, mode=mode,
+                block_table=block_table,
+            )
     elif isinstance(m, SSMSpec):
         y, new_self = ssm_layer(
             cfg, m, params["ssm"], h, cache=sc, mode=mode, positions=positions
@@ -140,13 +144,18 @@ def apply_layer(
 
     if ls.ffn.kind == "dense":
         h = rmsnorm(params["norm_ffn"], x, cfg.rms_eps)
-        x = x + mlp(params["ffn"], h, ls.ffn.act)
+        with jax.named_scope("mlp"):
+            x = x + mlp(params["ffn"], h, ls.ffn.act)
     elif ls.ffn.kind == "moe":
         h = rmsnorm(params["norm_ffn"], x, cfg.rms_eps)
-        if collect_stats:
-            y, aux, stats = moe_layer(cfg, ls.ffn, params["moe"], h, with_stats=True)
-        else:
-            y, aux = moe_layer(cfg, ls.ffn, params["moe"], h)
+        y, aux, *extra = moe_layer(cfg, ls.ffn, params["moe"], h,
+                                   with_stats=collect_stats, with_routed=count_routed)
+        if collect_stats or count_routed:
+            tel = {}
+            if collect_stats:
+                tel["stats"] = extra[0]
+            if count_routed:
+                tel["routed"] = extra[-1]
         x = x + y
 
     if new_cache is not None and "self" in new_cache:
@@ -156,8 +165,8 @@ def apply_layer(
         from repro.models.modules import grad_cast
 
         x = grad_cast(x)
-    if collect_stats:
-        return x, new_cache, aux, stats
+    if collect_stats or count_routed:
+        return x, new_cache, aux, tel
     return x, new_cache, aux
 
 
@@ -196,40 +205,44 @@ def apply_segment(
     remat: bool = False,
     block_table: Optional[jax.Array] = None,
     collect_stats: bool = False,
+    count_routed: bool = False,
 ):
     """Scan the segment.  caches (if given) mirror the params structure with a
     leading ``repeats`` axis.  Returns (x, new_caches, aux_sum); with
-    ``collect_stats=True`` a 4th element — ``{pos{j}: RoutingStats}`` for the
-    pattern's MoE positions, each leaf stacked ``[repeats, ...]`` by the scan
-    (per-layer telemetry falls out of the scan's ys stacking for free).
+    ``collect_stats`` or ``count_routed`` a 4th element — ``{pos{j}:
+    telemetry dict}`` (``apply_layer``) for the pattern's MoE positions, each
+    leaf stacked ``[repeats, ...]`` by the scan (per-layer telemetry falls
+    out of the scan's ys stacking for free).
 
     ``block_table`` (paged decode) is layer-invariant: every layer's page
     pool shares one table, so it rides into the scan body as a capture."""
     has_cache = caches is not None
+    collect = collect_stats or count_routed
 
     def body(carry, xs):
         x, aux = carry
         new_caches = {}
-        stats_out = {}
+        tel_out = {}
         for j, ls in enumerate(seg.pattern):
             pkey = f"pos{j}"
             c = xs[1][pkey] if has_cache else None
             out = apply_layer(
                 cfg, ls, xs[0][pkey], x, positions, cache=c, mode=mode, memory=memory,
                 block_table=block_table, collect_stats=collect_stats,
+                count_routed=count_routed,
             )
-            if collect_stats:
-                x, c_new, a, st = out
-                if st is not None:
-                    stats_out[pkey] = st
+            if collect:
+                x, c_new, a, tel = out
+                if tel is not None:
+                    tel_out[pkey] = tel
             else:
                 x, c_new, a = out
             if has_cache:
                 new_caches[pkey] = c_new
             aux = aux + a
         ys = new_caches if has_cache else 0
-        if collect_stats:
-            ys = (ys, stats_out)
+        if collect:
+            ys = (ys, tel_out)
         return (x, aux), ys
 
     if remat:
@@ -240,9 +253,9 @@ def apply_segment(
     (x, aux), ys = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), xs, unroll=_SCAN_UNROLL[0] or 1
     )
-    if collect_stats:
-        ys, stats = ys
-        return x, (ys if has_cache else None), aux, stats
+    if collect:
+        ys, tel = ys
+        return x, (ys if has_cache else None), aux, tel
     return x, (ys if has_cache else None), aux
 
 

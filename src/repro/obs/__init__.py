@@ -1,6 +1,7 @@
 """Observability layer: metrics (counters / gauges / log-bucket histograms),
-request-lifecycle tracing (Chrome/Perfetto trace_event JSON), and a retrace
-watchdog over the engines' jitted functions.  Dependency-free; see
+engine phase spans on the profiler's clock plus request-lifecycle tracing
+(Chrome/Perfetto trace_event JSON), and a retrace watchdog over the
+engines' jitted functions.  Needs nothing beyond JAX; see
 docs/OBSERVABILITY.md for the metric catalog and span taxonomy.
 
 ``Obs`` is the bundle the engines, the trainer, and launch/serve.py accept:
@@ -13,9 +14,9 @@ docs/OBSERVABILITY.md for the metric catalog and span taxonomy.
 
 Engines construct a default ``Obs()`` when none is injected: metrics stay on
 (they are the source of per-tick telemetry and cost ~µs/tick), the tracer is
-disabled (no-op fast path), and per-tick routing-stats collection is off
-(it changes the decode step's jitted signature, so it is an explicit
-opt-in).  ``Obs.disabled()`` turns the metrics off too — the benchmark
+disabled (its phase spans are then profiler annotations only), and per-tick
+routing-stats collection is off (it changes the decode step's jitted
+signature, so it is an explicit opt-in).  ``Obs.disabled()`` turns the metrics off too — the benchmark
 baseline for the overhead guard."""
 from __future__ import annotations
 

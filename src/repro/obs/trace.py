@@ -1,22 +1,32 @@
-"""Request-lifecycle tracer: spans and instants exported as Chrome/Perfetto
-``trace_event`` JSON.
+"""Engine phase spans on the profiler's clock, and a request-lifecycle
+tracer exported as Chrome/Perfetto ``trace_event`` JSON.
 
-The serving scheduler emits one *track* per decode slot (what physical
-resource was doing when), one per request (queued → admitted → prefill
-chunk(s) → decode → complete, plus preemption / CoW / prefix-hit instants),
-and one for the engine itself (tick spans, retrace warnings).  Tracks map
-onto Chrome's process/thread model: a track *group* ("slot", "request",
-"engine") becomes a pid, the id within the group becomes a tid, and metadata
-events name both so Perfetto renders labeled swimlanes.
+Two kinds of span, one bundle:
+
+* **Phase spans** (``Tracer.phase``): the engine's tick and its phases
+  (``engine.tick``, ``engine.admit``, ``engine.prefill``, ``engine.pages``,
+  ``engine.decode``, ``engine.sample``, ``engine.commit``, ``engine.record``,
+  ``engine.wait``).  Each always enters a ``jax.profiler.TraceAnnotation``
+  of the same fixed name, so a profiler trace holds them on the host line,
+  on the same clock as the device's operations; with no profiler session
+  running an annotation costs well under a microsecond.  When the tracer is
+  enabled the phase is also written as a B/E span on the ``engine 0`` track.
+* **Chrome-only tracks**: one *track* per decode slot (what physical
+  resource was doing when), one per request (queued -> admitted -> prefill
+  chunk(s) -> decode -> complete, plus preemption / CoW / prefix-hit
+  instants), and the ``engine`` track's instants (retrace warnings,
+  preemptions).  Tracks map onto Chrome's process/thread model: a track
+  *group* ("slot", "request", "engine") becomes a pid, the id within the
+  group becomes a tid, and metadata events name both so Perfetto renders
+  labeled swimlanes.
 
 Open ``chrome://tracing`` or https://ui.perfetto.dev and load the exported
 file (``Tracer.export`` / ``serve.py --trace-out``).
 
-Overhead contract: when ``enabled=False`` every method returns after a
-single attribute test — engines additionally hoist the check by holding
-``tracer if tracer.enabled else None`` — so tracing compiled into the
-serving hot path costs <1% of tick latency when off (asserted by the
-benchmarks ``obs`` section).
+Cost: when ``enabled=False`` every Chrome method returns after a single
+attribute test, and engines hoist the check by holding ``tracer if
+tracer.enabled else None``; a phase span then costs one profiler
+annotation.
 """
 from __future__ import annotations
 
@@ -24,7 +34,10 @@ import json
 import time
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 Track = Tuple[str, int]  # (group, id) -> (pid, tid)
+ENGINE: Track = ("engine", 0)
 
 
 class Tracer:
@@ -96,8 +109,15 @@ class Tracer:
         self._events.append(ev)
 
     def span(self, track: Track, name: str, args: Optional[dict] = None):
-        """``with tracer.span(("engine", 0), "tick"): ...``"""
+        """``with tracer.span(("slot", 0), "occupied"): ...`` (Chrome only)"""
         return _Span(self, track, name, args)
+
+    def phase(self, name: str):
+        """``with tracer.phase("engine.decode"): ...`` — a profiler
+        annotation named ``name``, always, and a span on the engine track
+        when the tracer is enabled.  ``name`` is a fixed string: readers of
+        the profiler trace group by it."""
+        return _Phase(self, name)
 
     def instant(self, track: Track, name: str, ts: Optional[float] = None,
                 args: Optional[dict] = None) -> None:
@@ -151,4 +171,25 @@ class _Span:
 
     def __exit__(self, *exc):
         self._tr.end(self._track)
+        return False
+
+
+class _Phase:
+    __slots__ = ("_tr", "_name", "_ann")
+
+    def __init__(self, tr: Tracer, name: str):
+        self._tr = tr
+        self._name = name
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._tr.enabled:
+            self._tr.begin(ENGINE, self._name)
+        return self
+
+    def __exit__(self, *exc):
+        if self._tr.enabled:
+            self._tr.end(ENGINE)
+        self._ann.__exit__(*exc)
         return False

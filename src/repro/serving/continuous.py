@@ -392,6 +392,9 @@ class ContinuousEngine:
         self.obs = obs if obs is not None else Obs()
         # hoisted enabled-check: the hot path tests one attribute, not three
         self._tr = self.obs.tracer if self.obs.tracer.enabled else None
+        # tick phases: profiler annotations always (free with no profiler
+        # running), engine-track spans too when the tracer is on
+        self._phase = self.obs.tracer.phase
         M = self.obs.metrics
         self._h_queue = M.histogram("serve.queue_wait_s")
         self._h_ttft = M.histogram("serve.ttft_s")
@@ -455,19 +458,25 @@ class ContinuousEngine:
             self._c_spec_rollback_pages = M.counter("spec.rolled_back_pages", unit="page")
             self._c_spec_resyncs = M.counter("spec.draft_resyncs")
 
+        def _telemetry(*tel):
+            # the decode step's third output: the routed-expert counts
+            # always (int32 [n_moe_layers], read back with the sampled
+            # tokens), the routing-stats tree when collection is on
+            out = {"experts_routed": tel[-1]}
+            if routing:
+                out["routing"] = tel[0]
+            return out
+
         if paged:
             def _step(params, tokens, positions, active, caches, tables):
-                # normalized 3-tuple return (routing = () when collection is
-                # off) so the call site rebinds the donated caches in one
-                # unpacking assignment — the donation auditor's required shape
-                out = paged_ragged_decode_step(
+                # normalized 3-tuple return so the call site rebinds the
+                # donated caches in one unpacking assignment — the donation
+                # auditor's required shape
+                logits, caches, *tel = paged_ragged_decode_step(
                     cfg, params, tokens, positions, active, caches, tables,
-                    return_routing=routing,
+                    return_routing=routing, return_routed=True,
                 )
-                if routing:
-                    return out
-                logits, caches = out
-                return logits, caches, ()
+                return logits, caches, _telemetry(*tel)
 
             self._decode = jax.jit(_step, donate_argnums=(4,))
 
@@ -563,12 +572,11 @@ class ContinuousEngine:
                         _spec_commit_fn, donate_argnums=(6,))
         else:
             def _step(params, tokens, positions, active, caches):
-                out = ragged_decode_step(cfg, params, tokens, positions, active, caches,
-                                         return_routing=routing)
-                if routing:
-                    return out
-                logits, caches = out
-                return logits, caches, ()
+                logits, caches, *tel = ragged_decode_step(
+                    cfg, params, tokens, positions, active, caches,
+                    return_routing=routing, return_routed=True,
+                )
+                return logits, caches, _telemetry(*tel)
 
             self._decode = jax.jit(_step, donate_argnums=(4,))
 
@@ -751,17 +759,22 @@ class ContinuousEngine:
     # Span taxonomy (docs/OBSERVABILITY.md): track ("request", rid) carries
     # queued -> prefill -> decode spans (preempted / prefix_hit / complete
     # instants); track ("slot", i) carries an occupancy span per admission
-    # with nested prefill-chunk spans; track ("engine", 0) carries tick
-    # spans plus cow_copy / preempt / retrace instants.
+    # with nested prefill-chunk spans; track ("engine", 0) carries the tick
+    # phase spans (``self._phase``, also profiler annotations) plus
+    # cow_copy / preempt / retrace instants.
 
-    def _obs_submitted(self, rid: int) -> None:
+    def _obs_submitted(self, rid: int, arrival_s: Optional[float] = None) -> None:
+        """Queue wait and TTFT run from ``arrival_s`` (the request's due
+        time on the ``perf_counter`` clock) when the caller knows it, else
+        from now."""
         self._c_submitted.inc()
+        t_submit = time.perf_counter() if arrival_s is None else arrival_s
         self._req_obs[rid] = {
-            "t_submit": time.perf_counter(), "t_admit": None, "t_first": None,
+            "t_submit": t_submit, "t_admit": None, "t_first": None,
             "t_last": None, "n_tokens": 0, "preempts": 0,
         }
         if self._tr:
-            self._tr.begin(("request", rid), "queued")
+            self._tr.begin(("request", rid), "queued", ts=t_submit)
 
     def _obs_admitted(self, rid: int, i: int) -> None:
         now = time.perf_counter()
@@ -836,7 +849,7 @@ class ContinuousEngine:
             rid=rid, prompt=list(req.prompt), budget=self._clamped_budget(req),
             generated=[], prompt_len=len(req.prompt),
         ))
-        self._obs_submitted(rid)
+        self._obs_submitted(rid, req.arrival_s)
         self._admit()
         return rid
 
@@ -862,7 +875,7 @@ class ContinuousEngine:
                 fork_of=rids[0] if j else -1,
             ))
             rids.append(rid)
-            self._obs_submitted(rid)
+            self._obs_submitted(rid, req.arrival_s)
         self._admit()
         return rids
 
@@ -912,10 +925,11 @@ class ContinuousEngine:
             self.caches, jnp.asarray(b, jnp.int32), jnp.asarray(i, jnp.int32)
         )
         self._key, sub = jax.random.split(self._key)
-        # analysis: allow(host-cast) — the fork's first token must reach the Python scheduler (slot state, _cur_token) before the next tick
-        first = int(sample(jnp.asarray(base.prefill_logits), sub,
-                           temperature=self.temperature,
-                           top_k=self.top_k, top_p=self.top_p)[0])
+        with self._phase("engine.wait"):
+            # analysis: allow(host-cast) — the fork's first token must reach the Python scheduler (slot state, _cur_token) before the next tick
+            first = int(sample(jnp.asarray(base.prefill_logits), sub,
+                               temperature=self.temperature,
+                               top_k=self.top_k, top_p=self.top_p)[0])
         self.slots[i] = SlotState(
             request_id=item.rid, pos=base.pos, generated=[first],
             budget=item.budget, active=True, admit_seq=self._admit_counter,
@@ -933,6 +947,10 @@ class ContinuousEngine:
         self._finish_if_done(i)
 
     def _admit(self) -> None:
+        with self._phase("engine.admit"):
+            self._admit_queue()
+
+    def _admit_queue(self) -> None:
         """FIFO admission: fill free slots from the queue head.  In paged
         mode a request is only admitted when the pool has enough free pages
         for its prompt (admission by free-block count); the queue head blocks
@@ -1027,9 +1045,10 @@ class ContinuousEngine:
             self.prefill_tokens_total += len(ctx)
             self._c_prefill_toks.inc(len(ctx))
             self._key, sub = jax.random.split(self._key)
-            # analysis: allow(host-cast) — admission's first sampled token feeds Python slot state; the sync is the admission boundary, not the tick
-            first = int(sample(logits, sub, temperature=self.temperature,
-                               top_k=self.top_k, top_p=self.top_p)[0])
+            with self._phase("engine.wait"):
+                # analysis: allow(host-cast) — admission's first sampled token feeds Python slot state; the sync is the admission boundary, not the tick
+                first = int(sample(logits, sub, temperature=self.temperature,
+                                   top_k=self.top_k, top_p=self.top_p)[0])
             # analysis: allow(host-asarray) — logits already host-synced by the cast above; the stash is what forks sample from without a recompute
             stash = np.asarray(logits) if self.prefix is not None else None
             self.slots[i] = SlotState(
@@ -1117,9 +1136,10 @@ class ContinuousEngine:
                     self.prefix.insert(ctx, [int(p) for p in self.tables.row(i)[:n_full]])
             if end == len(ctx):
                 self._key, sub = jax.random.split(self._key)
-                # analysis: allow(host-cast) — last-chunk logits seed the request's first token; it must land in Python slot state this tick
-                first = int(sample(logits, sub, temperature=self.temperature,
-                                   top_k=self.top_k, top_p=self.top_p)[0])
+                with self._phase("engine.wait"):
+                    # analysis: allow(host-cast) — last-chunk logits seed the request's first token; it must land in Python slot state this tick
+                    first = int(sample(logits, sub, temperature=self.temperature,
+                                       top_k=self.top_k, top_p=self.top_p)[0])
                 slot.prefilling = False
                 slot.prefill_ctx = []
                 slot.generated = slot.generated + [first]
@@ -1223,13 +1243,15 @@ class ContinuousEngine:
                     self.prefix.insert(ctx, [int(p) for p in self.tables.row(i)[:n_full]])
             if end == len(ctx):
                 if logits_host is None:
-                    # analysis: allow(host-asarray) — ONE sync serves every row finishing its prompt this tick; their first tokens must land in Python slot state
-                    logits_host = np.asarray(logits)
+                    with self._phase("engine.wait"):
+                        # analysis: allow(host-asarray) — ONE sync serves every row finishing its prompt this tick; their first tokens must land in Python slot state
+                        logits_host = np.asarray(logits)
                 row = logits_host[i : i + 1]
                 self._key, sub = jax.random.split(self._key)
-                # analysis: allow(host-cast) — the finishing row's first sampled token feeds Python slot state (eos/budget/fork decisions)
-                first = int(sample(jnp.asarray(row), sub, temperature=self.temperature,
-                                   top_k=self.top_k, top_p=self.top_p)[0])
+                with self._phase("engine.wait"):
+                    # analysis: allow(host-cast) — the finishing row's first sampled token feeds Python slot state (eos/budget/fork decisions)
+                    first = int(sample(jnp.asarray(row), sub, temperature=self.temperature,
+                                       top_k=self.top_k, top_p=self.top_p)[0])
                 slot.prefilling = False
                 slot.prefill_ctx = []
                 slot.generated = slot.generated + [first]
@@ -1478,167 +1500,159 @@ class ContinuousEngine:
         S, C = self.n_slots, self.spec_k + 1
 
         # --- draft: lazily (re)sync the drafter, then ONE propose scan
-        if self._tr:
-            self._tr.begin(("engine", 0), "spec_draft")
-        need_draft = []
-        for i, pl in plans.items():
-            slot = self.slots[i]
-            if pl["k"] == 0:
-                continue  # window is just the current token; nothing to draft
-            if self.drafter.needs_sync(i, slot.pos):
-                if self.drafter.next_pos[i] >= 0:
-                    self._c_spec_resyncs.inc()
-                    self._spec_tick_m["resyncs"] = \
-                        self._spec_tick_m.get("resyncs", 0) + 1
+        with self._phase("spec_draft"):
+            need_draft = []
+            for i, pl in plans.items():
+                slot = self.slots[i]
+                if pl["k"] == 0:
+                    continue  # window is just the current token; nothing to draft
+                if self.drafter.needs_sync(i, slot.pos):
+                    if self.drafter.next_pos[i] >= 0:
+                        self._c_spec_resyncs.inc()
+                        self._spec_tick_m["resyncs"] = \
+                            self._spec_tick_m.get("resyncs", 0) + 1
+                    self._jit_calls_tick += 1
+                    self.drafter.sync(i, slot.seq, slot.pos)
+                forced = slot.seq[int(self.drafter.next_pos[i]):slot.pos + 1]
+                need_draft.append((i, forced, pl["k"]))
+            if need_draft:
                 self._jit_calls_tick += 1
-                self.drafter.sync(i, slot.seq, slot.pos)
-            forced = slot.seq[int(self.drafter.next_pos[i]):slot.pos + 1]
-            need_draft.append((i, forced, pl["k"]))
-        if need_draft:
-            self._jit_calls_tick += 1
-            proposals = self.drafter.propose(need_draft)
-        else:
-            proposals = {}
-        for i, pl in plans.items():
-            pl["proposal"] = proposals.get(i, [])
-        if self._tr:
-            self._tr.end(("engine", 0))
+                proposals = self.drafter.propose(need_draft)
+            else:
+                proposals = {}
+            for i, pl in plans.items():
+                pl["proposal"] = proposals.get(i, [])
 
         # --- verify: all windows, one batched pass over the fork tables
-        if self._tr:
-            self._tr.begin(("engine", 0), "spec_verify")
-        tokens = np.zeros((S, C), np.int32)
-        positions = np.full((S, C), -1, np.int32)
-        active = np.zeros((S,), bool)
-        tbl = np.full((S, self.max_pages), -1, np.int32)
-        for i, pl in plans.items():
-            k, p = pl["k"], pl["p"]
-            tokens[i, 0] = self._cur_token[i]
-            tokens[i, 1:1 + k] = pl["proposal"]
-            positions[i, :k + 1] = np.arange(p, p + k + 1, dtype=np.int32)
-            active[i] = True
-            row = np.array(self.tables.row(i))
-            for e, pg in pl["window"].items():
-                row[e] = pg
-            tbl[i] = row
-        self._jit_calls_tick += 1
-        greedy_dev, self.caches = self._verify(
-            self.params, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(active), self.caches, jnp.asarray(tbl),
-        )
-        # analysis: allow(host-asarray) — THE per-tick sync: the target's greedy tokens drive accept/commit/rollback decisions on the host
-        greedy = np.asarray(greedy_dev)
-        if self._tr:
-            self._tr.end(("engine", 0))
+        with self._phase("spec_verify"):
+            tokens = np.zeros((S, C), np.int32)
+            positions = np.full((S, C), -1, np.int32)
+            active = np.zeros((S,), bool)
+            tbl = np.full((S, self.max_pages), -1, np.int32)
+            for i, pl in plans.items():
+                k, p = pl["k"], pl["p"]
+                tokens[i, 0] = self._cur_token[i]
+                tokens[i, 1:1 + k] = pl["proposal"]
+                positions[i, :k + 1] = np.arange(p, p + k + 1, dtype=np.int32)
+                active[i] = True
+                row = np.array(self.tables.row(i))
+                for e, pg in pl["window"].items():
+                    row[e] = pg
+                tbl[i] = row
+            self._jit_calls_tick += 1
+            greedy_dev, self.caches = self._verify(
+                self.params, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(active), self.caches, jnp.asarray(tbl),
+            )
+            with self._phase("engine.wait"):
+                # analysis: allow(host-asarray) — THE per-tick sync: the target's greedy tokens drive accept/commit/rollback decisions on the host
+                greedy = np.asarray(greedy_dev)
 
         # --- accept + commit/rollback bookkeeping (host side, in admission
         # order; greedy[i, j] is the target's token for position p + j + 1)
-        if self._tr:
-            self._tr.begin(("engine", 0), "spec_commit")
-        tail_pages = np.full((S,), -1, np.int32)
-        tail_offs = np.zeros((S,), np.int32)
-        reset_mask = np.zeros((self.n_pages + 1,), bool)
-        any_reset = False
-        commit_rows = []
-        emitted_total = accepted_total = drafted_total = 0
-        t_tok = time.perf_counter()
-        for i, pl in plans.items():
-            slot = self.slots[i]
-            k, p = pl["k"], pl["p"]
-            a = accept_length(pl["proposal"], greedy[i])
-            # accepted drafts, then the target's own token at the first
-            # disagreement (or the bonus token after a full accept)
-            appended = [int(t) for t in pl["proposal"][:a]] + [int(greedy[i, a])]
-            if self.eos_id >= 0 and self.eos_id in appended:
-                appended = appended[:appended.index(self.eos_id) + 1]
-            n = len(appended)  # 1 <= n <= k + 1 <= remaining budget
-            # pages: entries up to eb = page of the last ACCEPTED position
-            # commit; later window entries roll back.  n >= 1 makes eb >= e0
-            # always — the boundary entry commits on every outcome.
-            eb = (p + n - 1) // ps
-            if pl["boundary_fork"] >= 0:
-                freed = self.pool.commit_fork_run([pl["boundary_base"]], i)
-                self.tables.set_entry(i, pl["e0"], pl["boundary_fork"])
-                if freed:  # a sharer departed mid-tick and left us the base
-                    if self.prefix is not None:
-                        self.prefix.evict_pages(freed)
+        with self._phase("spec_commit"):
+            tail_pages = np.full((S,), -1, np.int32)
+            tail_offs = np.zeros((S,), np.int32)
+            reset_mask = np.zeros((self.n_pages + 1,), bool)
+            any_reset = False
+            commit_rows = []
+            emitted_total = accepted_total = drafted_total = 0
+            t_tok = time.perf_counter()
+            for i, pl in plans.items():
+                slot = self.slots[i]
+                k, p = pl["k"], pl["p"]
+                a = accept_length(pl["proposal"], greedy[i])
+                # accepted drafts, then the target's own token at the first
+                # disagreement (or the bonus token after a full accept)
+                appended = [int(t) for t in pl["proposal"][:a]] + [int(greedy[i, a])]
+                if self.eos_id >= 0 and self.eos_id in appended:
+                    appended = appended[:appended.index(self.eos_id) + 1]
+                n = len(appended)  # 1 <= n <= k + 1 <= remaining budget
+                # pages: entries up to eb = page of the last ACCEPTED position
+                # commit; later window entries roll back.  n >= 1 makes eb >= e0
+                # always — the boundary entry commits on every outcome.
+                eb = (p + n - 1) // ps
+                if pl["boundary_fork"] >= 0:
+                    freed = self.pool.commit_fork_run([pl["boundary_base"]], i)
+                    self.tables.set_entry(i, pl["e0"], pl["boundary_fork"])
+                    if freed:  # a sharer departed mid-tick and left us the base
+                        if self.prefix is not None:
+                            self.prefix.evict_pages(freed)
+                        reset_mask[freed] = True
+                        any_reset = True
+                grow = [pl["window"][e]
+                        for e in range(self.tables.n_mapped(i), eb + 1)]
+                if grow:
+                    self.tables.append(i, grow)
+                rollback = [pl["window"][e] for e in range(eb + 1, pl["e1"] + 1)]
+                if rollback:
+                    freed = self.pool.drop_fork_run(rollback, i)
                     reset_mask[freed] = True
                     any_reset = True
-            grow = [pl["window"][e]
-                    for e in range(self.tables.n_mapped(i), eb + 1)]
-            if grow:
-                self.tables.append(i, grow)
-            rollback = [pl["window"][e] for e in range(eb + 1, pl["e1"] + 1)]
-            if rollback:
-                freed = self.pool.drop_fork_run(rollback, i)
-                reset_mask[freed] = True
-                any_reset = True
-                self._c_spec_rollback_pages.inc(len(freed))
-            self._c_spec_commit_pages.inc(len(grow))
-            # position p + n - 1 is the last VALID write in page eb; verify
-            # writes beyond it (rejected drafts) are invalidated in one
-            # batched pass below, restoring the `already`-guard invariant
-            tail_pages[i] = int(self.tables.row(i)[eb])
-            tail_offs[i] = (p + n - 1) % ps + 1
-            # slot state: appended[-1] is the new sampled-but-unwritten token
-            slot.pos = p + n
-            slot.generated.extend(appended)
-            slot.seq.extend(appended)
-            slot.prefill_logits = None
-            self._cur_token[i] = appended[-1]
-            if self.drafter.after_commit(i, p, k, a == k, slot.pos):
-                self._c_spec_resyncs.inc()
-                self._spec_tick_m["resyncs"] = \
-                    self._spec_tick_m.get("resyncs", 0) + 1
-            emitted_total += n
-            accepted_total += a
-            drafted_total += k
-            self._c_spec_verifies.inc()
-            self._c_spec_drafted.inc(k)
-            self._c_spec_accepted.inc(a)
-            if k:
-                self._h_accept.observe(a / k)
-            self._h_tok_verify.observe(float(n))
-            if self._tr:
-                self._tr.instant(("request", slot.request_id), "spec_commit",
-                                 ts=t_tok, args={"drafted": k, "accepted": a,
-                                                 "emitted": n})
-            commit_rows.append((i, pl, appended))
+                    self._c_spec_rollback_pages.inc(len(freed))
+                self._c_spec_commit_pages.inc(len(grow))
+                # position p + n - 1 is the last VALID write in page eb; verify
+                # writes beyond it (rejected drafts) are invalidated in one
+                # batched pass below, restoring the `already`-guard invariant
+                tail_pages[i] = int(self.tables.row(i)[eb])
+                tail_offs[i] = (p + n - 1) % ps + 1
+                # slot state: appended[-1] is the new sampled-but-unwritten token
+                slot.pos = p + n
+                slot.generated.extend(appended)
+                slot.seq.extend(appended)
+                slot.prefill_logits = None
+                self._cur_token[i] = appended[-1]
+                if self.drafter.after_commit(i, p, k, a == k, slot.pos):
+                    self._c_spec_resyncs.inc()
+                    self._spec_tick_m["resyncs"] = \
+                        self._spec_tick_m.get("resyncs", 0) + 1
+                emitted_total += n
+                accepted_total += a
+                drafted_total += k
+                self._c_spec_verifies.inc()
+                self._c_spec_drafted.inc(k)
+                self._c_spec_accepted.inc(a)
+                if k:
+                    self._h_accept.observe(a / k)
+                self._h_tok_verify.observe(float(n))
+                if self._tr:
+                    self._tr.instant(("request", slot.request_id), "spec_commit",
+                                     ts=t_tok, args={"drafted": k, "accepted": a,
+                                                     "emitted": n})
+                commit_rows.append((i, pl, appended))
 
-        # --- committed recurrent-state pass (window rings / SSM / LRU /
-        # conv): re-run the accepted tokens through the batched chunk entry
-        # so per-slot leaves advance; pool writes are `already`-trash-routed
-        if self._spec_commit is not None:
-            tokens2 = np.zeros((S, C), np.int32)
-            positions2 = np.full((S, C), -1, np.int32)
-            active2 = np.zeros((S,), bool)
-            last2 = np.zeros((S,), np.int32)
-            tbl2 = np.full((S, self.max_pages), -1, np.int32)
-            for i, pl, appended in commit_rows:
-                slot = self.slots[i]
-                n, p = len(appended), pl["p"]
-                tokens2[i, :n] = np.asarray(slot.seq[p:p + n], np.int32)
-                positions2[i, :n] = np.arange(p, p + n, dtype=np.int32)
-                active2[i] = True
-                last2[i] = n - 1
-                tbl2[i] = self.tables.row(i)
-            self._jit_calls_tick += 1
-            _, self.caches = self._spec_commit(
-                self.params, jnp.asarray(tokens2), jnp.asarray(positions2),
-                jnp.asarray(np.zeros((S,), bool)), jnp.asarray(active2),
-                jnp.asarray(last2), self.caches, jnp.asarray(tbl2),
-            )
+            # --- committed recurrent-state pass (window rings / SSM / LRU /
+            # conv): re-run the accepted tokens through the batched chunk entry
+            # so per-slot leaves advance; pool writes are `already`-trash-routed
+            if self._spec_commit is not None:
+                tokens2 = np.zeros((S, C), np.int32)
+                positions2 = np.full((S, C), -1, np.int32)
+                active2 = np.zeros((S,), bool)
+                last2 = np.zeros((S,), np.int32)
+                tbl2 = np.full((S, self.max_pages), -1, np.int32)
+                for i, pl, appended in commit_rows:
+                    slot = self.slots[i]
+                    n, p = len(appended), pl["p"]
+                    tokens2[i, :n] = np.asarray(slot.seq[p:p + n], np.int32)
+                    positions2[i, :n] = np.arange(p, p + n, dtype=np.int32)
+                    active2[i] = True
+                    last2[i] = n - 1
+                    tbl2[i] = self.tables.row(i)
+                self._jit_calls_tick += 1
+                _, self.caches = self._spec_commit(
+                    self.params, jnp.asarray(tokens2), jnp.asarray(positions2),
+                    jnp.asarray(np.zeros((S,), bool)), jnp.asarray(active2),
+                    jnp.asarray(last2), self.caches, jnp.asarray(tbl2),
+                )
 
-        # --- device-side invalidation: committed-page tails (always — one
-        # fixed-shape call per commit tick) and rollback-freed pages
-        self._jit_calls_tick += 1
-        self.caches = self._spec_reset_tail(
-            self.caches, jnp.asarray(tail_pages), jnp.asarray(tail_offs))
-        if any_reset:
+            # --- device-side invalidation: committed-page tails (always — one
+            # fixed-shape call per commit tick) and rollback-freed pages
             self._jit_calls_tick += 1
-            self.caches = self._reset_pages(self.caches, jnp.asarray(reset_mask))
-        if self._tr:
-            self._tr.end(("engine", 0))
+            self.caches = self._spec_reset_tail(
+                self.caches, jnp.asarray(tail_pages), jnp.asarray(tail_offs))
+            if any_reset:
+                self._jit_calls_tick += 1
+                self.caches = self._reset_pages(self.caches, jnp.asarray(reset_mask))
 
         # --- completions last: _finish_if_done may release the slot and
         # cascade an admission into it (whose first chunk must not be
@@ -1664,11 +1678,28 @@ class ContinuousEngine:
         callers keep ticking while long admissions are still being written.
         Per-tick scheduler telemetry lands in ``last_metrics`` /
         ``metrics_log`` (active slots, prefill/decode token counts,
-        free/shared pages, CoW copies, tok/s, preemptions)."""
+        free/shared pages, CoW copies, tok/s, preemptions, routed experts).
+
+        The tick and its phases are spans (``engine.tick`` around
+        ``engine.prefill``, ``engine.pages``, ``engine.decode``,
+        ``engine.sample``, ``engine.commit``, ``engine.record``, with
+        ``engine.admit`` wherever admission runs and ``engine.wait`` inside
+        whichever phase blocks on the device), so a profiler trace splits
+        the host's tick by phase on the device's clock."""
+        with self._phase("engine.tick"):
+            return self._run_tick()
+
+    def _fence(self) -> None:
+        """End-of-tick fence: the donated cache updates are still in flight
+        after the token readback; without it ``tick_s`` under-reports the
+        device time the tick consumed, and a caller stamping tokens at the
+        tick's return would stamp them early."""
+        with self._phase("engine.wait"):
+            # analysis: allow(block-sync) — deliberate end-of-tick fence for tick latency accounting
+            jax.block_until_ready(self.caches)
+
+    def _run_tick(self) -> int:
         t0 = time.perf_counter()
-        if self._tr:
-            self._tr.begin(("engine", 0), "tick", ts=t0,
-                           args={"tick": self._tick + 1})
         if self.paged and self.prefill_mode in ("chunked", "batched"):
             # bounded head-of-line blocking: decode (below) runs every tick,
             # delayed by at most this one chunk of prefill compute — the
@@ -1681,105 +1712,96 @@ class ContinuousEngine:
             self._admit()
             if not any(s.active for s in self.slots):
                 self._end_tick_prefill()
-                if self._tr:
-                    self._tr.end(("engine", 0))
                 return 0
         if self._tick_budget is not None:
-            if self.prefill_mode == "batched":
-                self._prefill_tick_batched()
-            else:
-                self._prefill_tick()
+            with self._phase("engine.prefill"):
+                if self.prefill_mode == "batched":
+                    self._prefill_tick_batched()
+                else:
+                    self._prefill_tick()
         if self.paged and self.drafter is None:
             # spec mode skips this: _spec_plan_pages subsumes lazy growth and
             # CoW for the whole k+1 window, fork-first instead of copy-first
-            self._ensure_pages()
+            with self._phase("engine.pages"):
+                self._ensure_pages()
         # rows eligible to decode this tick — mid-prefill slots are excluded,
         # and their table rows are masked out of the decode step so its pool
         # writes land in the trash page, never in a half-written prompt page
         decoding = np.asarray([s.active and not s.prefilling for s in self.slots])
         n_active = int(sum(s.active for s in self.slots))
-        ran_prefill = self._tick_prefill_done > 0
-        if ran_prefill:
-            # fence the async chunk writes so the prefill/decode timer split
-            # attributes device time to the phase that spent it
-            # analysis: allow(block-sync) — deliberate timing fence for phase attribution
-            jax.block_until_ready(self.caches)
-        t_mid = time.perf_counter()
         if not decoding.any():
-            prefill_toks = self._end_tick_prefill()
+            with self._phase("engine.commit"):
+                prefill_toks = self._end_tick_prefill()
+                if prefill_toks:
+                    self._fence()
             if n_active or prefill_toks:
-                self._record_metrics(0, t_mid - t0, prefill_toks, n_active,
-                                     prefill_s=t_mid - t0)
-            if self._tr:
-                self._tr.end(("engine", 0))
+                with self._phase("engine.record"):
+                    self._record_metrics(0, time.perf_counter() - t0, prefill_toks, n_active)
             return n_active
         if self.drafter is not None:
             # speculative path: the whole draft/verify/commit tick replaces
             # the one-token decode step below (same tick telemetry shape)
             n_decoded = self._spec_decode_tick(decoding)
-            prefill_toks = self._end_tick_prefill()
-            # analysis: allow(block-sync) — tick boundary fence, same as the non-speculative tail below
-            jax.block_until_ready(self.caches)
-            t1 = time.perf_counter()
-            self._record_metrics(n_decoded, t1 - t0, prefill_toks, n_active,
-                                 prefill_s=t_mid - t0, decode_s=t1 - t_mid)
-            if self._tr:
-                self._tr.end(("engine", 0))
+            with self._phase("engine.commit"):
+                prefill_toks = self._end_tick_prefill()
+                self._fence()
+            with self._phase("engine.record"):
+                self._record_metrics(n_decoded, time.perf_counter() - t0, prefill_toks, n_active)
             return n_active
-        positions = np.asarray([s.pos if s.active else 0 for s in self.slots], np.int32)
-        tokens = jnp.asarray(self._cur_token[:, None])
-        self._jit_calls_tick += 1
-        if self.paged:
-            tbl = np.where(decoding[:, None], self.tables.table, -1)
-            logits, self.caches, routing_tree = self._decode(
-                self.params, tokens, jnp.asarray(positions), jnp.asarray(decoding),
-                self.caches, jnp.asarray(tbl),
-            )
-        else:
-            logits, self.caches, routing_tree = self._decode(
-                self.params, tokens, jnp.asarray(positions), jnp.asarray(decoding), self.caches
-            )
-        self._key, sub = jax.random.split(self._key)
-        # analysis: allow(host-asarray) — THE per-tick sync: sampled tokens drive eos/budget/admission decisions on the host
-        nxt = np.asarray(sample(logits, sub, temperature=self.temperature,
-                                top_k=self.top_k, top_p=self.top_p))
+        with self._phase("engine.decode"):
+            positions = np.asarray([s.pos if s.active else 0 for s in self.slots], np.int32)
+            tokens = jnp.asarray(self._cur_token[:, None])
+            self._jit_calls_tick += 1
+            if self.paged:
+                tbl = np.where(decoding[:, None], self.tables.table, -1)
+                logits, self.caches, tel = self._decode(
+                    self.params, tokens, jnp.asarray(positions), jnp.asarray(decoding),
+                    self.caches, jnp.asarray(tbl),
+                )
+            else:
+                logits, self.caches, tel = self._decode(
+                    self.params, tokens, jnp.asarray(positions), jnp.asarray(decoding), self.caches
+                )
+        with self._phase("engine.sample"):
+            self._key, sub = jax.random.split(self._key)
+            nxt_dev = sample(logits, sub, temperature=self.temperature,
+                             top_k=self.top_k, top_p=self.top_p)
+            with self._phase("engine.wait"):
+                # analysis: allow(host-asarray) — THE per-tick sync: sampled tokens drive eos/budget/admission decisions on the host; the routed-expert counts ride in the same transfer
+                nxt, routed = jax.device_get((nxt_dev, tel["experts_routed"]))
         n_decoded = int(decoding.sum())
         t_tok = time.perf_counter()
-        for i, slot in enumerate(self.slots):
-            # Gate on the PRE-decode snapshot, not slot.active: a completion
-            # at row < i can trigger _admit into free row i mid-loop, and
-            # that fresh slot must not consume nxt[i] — its logits row was
-            # computed while the row was inactive (or still prefilling).
-            if not decoding[i]:
-                continue
-            slot.pos += 1
-            slot.generated.append(int(nxt[i]))
-            slot.seq.append(int(nxt[i]))
-            # the stashed admission logits are only consumable by a fork
-            # BEFORE the base's first decode tick — drop the dead copy
-            slot.prefill_logits = None
-            self._cur_token[i] = int(nxt[i])
-            self._obs_token(slot.request_id, t_tok)
-            self._finish_if_done(i)
-        prefill_toks = self._end_tick_prefill()
-        # fetching nxt blocked on the logits, but the donated cache updates
-        # are still in flight — without this fence the recorded tick latency
-        # under-reports the device time the tick actually consumed
-        # analysis: allow(block-sync) — deliberate timing fence for tick latency accounting
-        jax.block_until_ready(self.caches)
+        with self._phase("engine.commit"):
+            for i, slot in enumerate(self.slots):
+                # Gate on the PRE-decode snapshot, not slot.active: a
+                # completion at row < i can trigger _admit into free row i
+                # mid-loop, and that fresh slot must not consume nxt[i] — its
+                # logits row was computed while the row was inactive (or
+                # still prefilling).
+                if not decoding[i]:
+                    continue
+                slot.pos += 1
+                slot.generated.append(int(nxt[i]))
+                slot.seq.append(int(nxt[i]))
+                # the stashed admission logits are only consumable by a fork
+                # BEFORE the base's first decode tick — drop the dead copy
+                slot.prefill_logits = None
+                self._cur_token[i] = int(nxt[i])
+                self._obs_token(slot.request_id, t_tok)
+                self._finish_if_done(i)
+            prefill_toks = self._end_tick_prefill()
+            self._fence()
         t1 = time.perf_counter()
-        routing_m = summarize_routing(routing_tree) if routing_tree else None
-        self._record_metrics(n_decoded, t1 - t0, prefill_toks, n_active,
-                             prefill_s=t_mid - t0, decode_s=t1 - t_mid,
-                             routing=routing_m)
-        if self._tr:
-            self._tr.end(("engine", 0), ts=t1, args={"decoded": n_decoded})
+        with self._phase("engine.record"):
+            routing_m = summarize_routing(tel["routing"]) if tel.get("routing") else None
+            self._record_metrics(n_decoded, t1 - t0, prefill_toks, n_active,
+                                 routing=routing_m, experts_routed=routed)
         return n_active
 
     def _record_metrics(self, n_decoded: int, dt: float, prefill_toks: int = 0,
                         n_active: Optional[int] = None, *,
-                        prefill_s: float = 0.0, decode_s: Optional[float] = None,
-                        routing: Optional[dict] = None) -> None:
+                        routing: Optional[dict] = None,
+                        experts_routed: Optional[np.ndarray] = None) -> None:
         retraces = self.obs.watchdog.tick()
         if retraces:
             self._c_retraces.inc(retraces)
@@ -1802,12 +1824,6 @@ class ContinuousEngine:
             "tokens_this_tick": n_decoded,
             "tok_per_s": round(n_decoded / max(dt, 1e-9), 2),
             "tick_s": round(dt, 6),
-            # decode throughput over the decode phase only (the legacy
-            # tok_per_s divides by the WHOLE tick, prefill included)
-            "decode_tok_per_s": round(n_decoded / max(decode_s, 1e-9), 2)
-            if decode_s is not None else 0.0,
-            "prefill_tok_per_s": round(prefill_toks / max(prefill_s, 1e-9), 2)
-            if prefill_toks else 0.0,
             "retraces": retraces,
             "preemptions": self.preemptions,
             # jitted-function invocations attributed to this tick (including
@@ -1821,6 +1837,11 @@ class ContinuousEngine:
             m["batched_prefill_occupancy"] = round(self._batched_occ_tick, 4)
             self._g_batch_occ.set(round(self._batched_occ_tick, 4))
             self._batched_occ_tick = 0.0
+        if experts_routed is not None:
+            # per MoE layer, in layer order: experts the decode step's
+            # dispatch gave at least one row (padding rows included) — whose
+            # weights the step read
+            m["experts_routed"] = experts_routed.tolist()
         if routing is not None:
             self._g_r_drop.set(routing["dropped_frac"])
             self._g_r_ent.set(routing["entropy"])

@@ -58,6 +58,11 @@ class EngineConfig:
 class Request:
     prompt: Sequence[int]
     max_new_tokens: int = 16
+    # when the request was due, on the ``time.perf_counter`` clock; the
+    # continuous engine's queue-wait and TTFT timers start here when it is
+    # set (a caller that submits late still charges the wait), else at
+    # ``submit()``
+    arrival_s: Optional[float] = None
 
 
 @dataclass
@@ -109,7 +114,7 @@ class Engine:
         # same default contract as ContinuousEngine: metrics on, tracer off,
         # routing collection off (it changes the decode step's signature)
         self.obs = obs if obs is not None else Obs()
-        self._tr = self.obs.tracer if self.obs.tracer.enabled else None
+        self._phase = self.obs.tracer.phase
         routing = self.obs.routing
         M = self.obs.metrics
         self._h_prefill = M.histogram("serve.batch_prefill_s")
@@ -243,21 +248,16 @@ class Engine:
             toks[i, S - len(p) :] = p
 
         caches = self._make_caches(B)
-        tr = self._tr
         t0 = time.perf_counter()
-        if tr:
-            tr.begin(("engine", 0), "prefill", ts=t0,
-                     args={"batch": B, "prompt_len": S})
-        logits, caches = self._prefill(
-            self.params, jnp.asarray(toks), caches, self.memory, self.prefix_embeds
-        )
-        if self.obs.metrics.enabled or tr:
-            # analysis: allow(block-sync) — deliberate timing fence for the prefill histogram
-            jax.block_until_ready(logits)
-            t1 = time.perf_counter()
-            self._h_prefill.observe(t1 - t0)
-            if tr:
-                tr.end(("engine", 0), ts=t1)
+        with self._phase("engine.prefill"):
+            logits, caches = self._prefill(
+                self.params, jnp.asarray(toks), caches, self.memory, self.prefix_embeds
+            )
+            if self.obs.metrics.enabled or self.obs.tracer.enabled:
+                with self._phase("engine.wait"):
+                    # analysis: allow(block-sync) — deliberate timing fence for the prefill histogram
+                    jax.block_until_ready(logits)
+                self._h_prefill.observe(time.perf_counter() - t0)
         offset = (
             self.cfg.frontend.n_tokens if (cfg.frontend is not None and cfg.family == "vlm") else 0
         )
@@ -266,40 +266,38 @@ class Engine:
         generated = np.zeros((B, max_new), np.int32)
         done = np.zeros((B,), bool)
         cur = sample(logits, key, temperature=ec.temperature, top_k=ec.top_k, top_p=ec.top_p)
-        if tr:
-            tr.begin(("engine", 0), "decode", args={"batch": B})
         t_prev = time.perf_counter()
         for t in range(max_new):
-            # analysis: allow(host-asarray) — THE per-step sync: tokens drive host-side eos/stop logic while the next step is dispatched
-            generated[:, t] = np.asarray(cur)  # blocks on the in-flight step
-            now = time.perf_counter()
-            if t:  # step t-1's device time ended at this sync point
-                self._h_step.observe(now - t_prev)
-            t_prev = now
-            self._c_decode_toks.inc(int((~done).sum()))
-            done |= generated[:, t] == ec.eos_id
-            if done.all():
-                generated = generated[:, : t + 1]
-                break
-            key, sub = jax.random.split(key)
-            idx = jnp.asarray(S + offset + t, jnp.int32)
-            # single unpacking assignment: the donated caches are rebound by
-            # the same statement that calls the donating function
-            logits, caches, routing_tree = self._decode(
-                self.params, cur[:, None], idx, caches, self.memory
-            )
-            if self.obs.routing:
-                self.last_routing = summarize_routing(routing_tree) if routing_tree else None
-                if self.last_routing:
-                    self._g_r_drop.set(self.last_routing["dropped_frac"])
-                    self._g_r_ent.set(self.last_routing["entropy"])
-                    self._g_r_imb.set(self.last_routing["imbalance"])
-            fresh = self.obs.watchdog.tick()
-            if fresh:
-                self._c_retraces.inc(fresh)
-            cur = sample(logits, sub, temperature=ec.temperature, top_k=ec.top_k, top_p=ec.top_p)
-        if tr:
-            tr.end(("engine", 0))
+            with self._phase("engine.decode"):
+                with self._phase("engine.wait"):
+                    # analysis: allow(host-asarray) — THE per-step sync: tokens drive host-side eos/stop logic while the next step is dispatched
+                    generated[:, t] = np.asarray(cur)  # blocks on the in-flight step
+                now = time.perf_counter()
+                if t:  # step t-1's device time ended at this sync point
+                    self._h_step.observe(now - t_prev)
+                t_prev = now
+                self._c_decode_toks.inc(int((~done).sum()))
+                done |= generated[:, t] == ec.eos_id
+                if done.all():
+                    generated = generated[:, : t + 1]
+                    break
+                key, sub = jax.random.split(key)
+                idx = jnp.asarray(S + offset + t, jnp.int32)
+                # single unpacking assignment: the donated caches are rebound
+                # by the same statement that calls the donating function
+                logits, caches, routing_tree = self._decode(
+                    self.params, cur[:, None], idx, caches, self.memory
+                )
+                if self.obs.routing:
+                    self.last_routing = summarize_routing(routing_tree) if routing_tree else None
+                    if self.last_routing:
+                        self._g_r_drop.set(self.last_routing["dropped_frac"])
+                        self._g_r_ent.set(self.last_routing["entropy"])
+                        self._g_r_imb.set(self.last_routing["imbalance"])
+                fresh = self.obs.watchdog.tick()
+                if fresh:
+                    self._c_retraces.inc(fresh)
+                cur = sample(logits, sub, temperature=ec.temperature, top_k=ec.top_k, top_p=ec.top_p)
         self._c_completed.inc(B)
 
         res = []

@@ -36,11 +36,12 @@ def sample(
     top_p: float = 0.0,
 ) -> jax.Array:
     """logits [B, V] -> tokens [B]."""
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits.astype(jnp.float32) / temperature
-    if top_k > 0:
-        logits = _top_k_mask(logits, top_k)
-    if 0.0 < top_p < 1.0:
-        logits = _top_p_mask(logits, top_p)
-    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits = logits.astype(jnp.float32) / temperature
+        if top_k > 0:
+            logits = _top_k_mask(logits, top_k)
+        if 0.0 < top_p < 1.0:
+            logits = _top_p_mask(logits, top_p)
+        return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)

@@ -56,6 +56,9 @@ from repro.models.model import init_params
 from repro.serving.continuous import ContinuousEngine
 from repro.serving.engine import Engine, EngineConfig, Request
 
+def routed(eng):
+    return [m["experts_routed"] for m in eng.metrics_log if "experts_routed" in m]
+
 def serve(cfg, params, prompts, n_new, **kw):
     eng = ContinuousEngine(cfg, params, **kw)
     ids = [eng.submit(Request(prompt=p, max_new_tokens=n_new)) for p in prompts]
@@ -86,16 +89,19 @@ print("glm4 (8,) OK")
         chunked-prefill dense kernel goes through the hierarchical two-hop
         a2a, decode through the replicated-token all_gather schedule.
         capacity_factor=8.0 gives the a2a schedule drop-free headroom (the
-        parity-by-construction precondition for the token-sharded path)."""
+        parity-by-construction precondition for the token-sharded path).
+        Each decode tick's routed-expert counts, taken from the sharded
+        dispatch, match the single-device engine's."""
         run_script(ENGINE_PREAMBLE + """
 cfg = with_moe_ffn(make_reduced(all_configs()["nlg-350m-moe128"]),
                    num_experts=8, capacity_factor=8.0)
 params = init_params(cfg, jax.random.PRNGKey(0))
 kw = dict(slots=4, capacity=64, paged=True, page_size=8)
-base, _ = serve(cfg, params, PROMPTS, 8, **kw)
+base, beng = serve(cfg, params, PROMPTS, 8, **kw)
 ep, eng = serve(cfg.replace(ep_mesh=(4, 2)), params, PROMPTS, 8, **kw)
 assert eng.cfg.moe_impl == "ep_serve", eng.cfg.moe_impl
 assert base == ep, (base, ep)
+assert routed(eng) and routed(eng) == routed(beng), (routed(beng), routed(eng))
 print("nlg (4,2) OK")
 """)
 
@@ -114,17 +120,19 @@ print("gemma3 int8 (4,2) OK")
 
     def test_nlg_grouped_batched_prefix(self):
         """Composition: grouped (dropless) expert kernel per device + batched
-        multi-slot prefill + prefix sharing, experts over (2, 4)."""
+        multi-slot prefill + prefix sharing, experts over (2, 4); routed-expert
+        counts as on one device."""
         run_script(ENGINE_PREAMBLE + """
 cfg = with_moe_ffn(make_reduced(all_configs()["nlg-350m-moe128"]), num_experts=8)
 cfg = cfg.replace(moe_impl="grouped")
 params = init_params(cfg, jax.random.PRNGKey(0))
 kw = dict(slots=4, capacity=64, paged=True, page_size=8,
           prefix_sharing=True, prefill_mode="batched")
-base, _ = serve(cfg, params, PROMPTS, 8, **kw)
+base, beng = serve(cfg, params, PROMPTS, 8, **kw)
 ep, eng = serve(cfg.replace(ep_mesh=(2, 4)), params, PROMPTS, 8, **kw)
 assert eng.cfg.moe_impl == "ep_grouped", eng.cfg.moe_impl
 assert base == ep, (base, ep)
+assert routed(eng) and routed(eng) == routed(beng), (routed(beng), routed(eng))
 print("nlg grouped batched prefix (2,4) OK")
 """)
 

@@ -10,7 +10,15 @@ prefill chunks, 1056-token capacity.  Nothing here runs or times anything.
 
 The topology is described inside fixtures, never at import: only one process
 at a time may load the TPU library, and every test worker imports this file.
+
+Each compiled kernel's custom call must also carry the name the benchmark's
+trace reduction matches it by (``bench.trace.KERNELS``): a rename fails here
+before it silences a per-layer metric on the chip.
 """
+import re
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -45,9 +53,22 @@ def _sds(sharding):
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(lowered):
-    text = lowered.compile().as_text()
-    assert "tpu_custom_call" in text
+CUSTOM_CALL = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*custom_call_target="tpu_custom_call"',
+                         re.MULTILINE)
+
+
+def _assert_kernel(lowered, kernel):
+    """The program compiles to Mosaic custom calls, and each is named as
+    the trace reduction's pattern for ``kernel`` expects."""
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.trace import KERNELS
+
+    names = CUSTOM_CALL.findall(lowered.compile().as_text())
+    assert names, "no tpu_custom_call in the compiled program"
+    for name in names:
+        assert KERNELS[kernel].match(name), (name, KERNELS[kernel].pattern)
 
 
 def _pool(s, quantized):
@@ -65,7 +86,8 @@ def test_paged_decode_compiles(one_chip, quantized):
     s = _sds(one_chip)
     q = s((SLOTS, HKV, H // HKV, DH), jnp.bfloat16)
     args = (q, *_pool(s, quantized), s((SLOTS, NT), jnp.int32), s((SLOTS, 1), jnp.int32))
-    _assert_kernel(paged_decode_attention.lower(*args, scale=DH ** -0.5, interpret=False))
+    _assert_kernel(paged_decode_attention.lower(*args, scale=DH ** -0.5, interpret=False),
+                   "paged_decode_attn")
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -77,7 +99,8 @@ def test_chunk_prefill_compiles(one_chip, quantized):
     chunk_kv = s((SLOTS, CHUNK, HKV, DH), jnp.bfloat16)
     args = (q, *_pool(s, quantized), s((SLOTS, NT), jnp.int32),
             s((SLOTS, CHUNK), jnp.int32), chunk_kv, chunk_kv)
-    _assert_kernel(paged_prefill_attention.lower(*args, scale=DH ** -0.5, interpret=False))
+    _assert_kernel(paged_prefill_attention.lower(*args, scale=DH ** -0.5, interpret=False),
+                   "prefill_attn")
 
 
 @pytest.mark.parametrize("act", ["gelu", "swiglu"])
@@ -90,7 +113,7 @@ def test_grouped_mlp_compiles(one_chip, act):
     lowered = grouped_mlp_kernel.lower(
         s((CT, D), jnp.bfloat16), s((CT // 8,), jnp.int32), up, wg,
         s((E, F, D), jnp.bfloat16), act=act, interpret=False)
-    _assert_kernel(lowered)
+    _assert_kernel(lowered, "grouped_mlp")
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -104,7 +127,7 @@ def test_grouped_mlp_quant_compiles(one_chip, bits):
         s((E, D // pack, F), jnp.int8), s((E, 1, F), jnp.float32), None, None,
         s((E, F // pack, D), jnp.int8), s((E, 1, D), jnp.float32),
         bits=bits, act="gelu", interpret=False)
-    _assert_kernel(lowered)
+    _assert_kernel(lowered, "grouped_mlp")
 
 
 def test_paged_decode_under_ep_mesh_compiles(topo, monkeypatch):
@@ -135,4 +158,4 @@ def test_paged_decode_under_ep_mesh_compiles(topo, monkeypatch):
             jax.ShapeDtypeStruct((SLOTS, 1, H, DH), jnp.bfloat16, sharding=rows), cache,
             jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=rows),
             jax.ShapeDtypeStruct((SLOTS, NT), jnp.int32, sharding=rows))
-    _assert_kernel(lowered)
+    _assert_kernel(lowered, "paged_decode_attn")
