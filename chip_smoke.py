@@ -186,13 +186,14 @@ def compare(name: str, got, want) -> None:
 
 
 def random_pool(rng, Pt: int, Hkv: int, dh: int, nt: int, mapped, written):
-    """A random bf16 page pool and the clamped block tables of one sequence
-    per row: ``mapped[b]`` positions have pages (distinct; the last page is
-    trash), the first ``written[b]`` of them hold keys (``pos`` >= 0)."""
+    """A random bf16 page pool (token rows ``[Pt, page_size, Hkv*dh]``) and
+    the clamped block tables of one sequence per row: ``mapped[b]``
+    positions have pages (distinct; the last page is trash), the first
+    ``written[b]`` of them hold keys (``pos`` >= 0)."""
     import jax.numpy as jnp
 
-    k = jnp.asarray(rng.standard_normal((Pt, Hkv, PAGE_SIZE, dh)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((Pt, Hkv, PAGE_SIZE, dh)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((Pt, PAGE_SIZE, Hkv * dh)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((Pt, PAGE_SIZE, Hkv * dh)), jnp.bfloat16)
     kpos = np.full((Pt, PAGE_SIZE), -1, np.int32)
     table = np.full((len(mapped), nt), Pt - 1, np.int32)
     free = list(rng.permutation(Pt - 1))
@@ -227,12 +228,14 @@ def kernel_checks(engine, params, cfg, seed: int) -> None:
     scale = dh ** -0.5
     ref = lambda f, *a, **kw: jax.jit(functools.partial(f, **kw))(*a)
 
-    # paged decode: one query per slot at the end of a random-length history
+    # paged decode: one query per slot at the end of a random-length history,
+    # read from the last of two stacked layer pools
     lengths = rng.integers(1, engine.capacity + 1, size=SLOTS)
     k, v, kpos, table = random_pool(rng, Pt, Hkv, dh, nt, lengths, lengths)
+    stack = lambda a: jnp.stack([jnp.zeros_like(a), a])
     q = jnp.asarray(rng.standard_normal((SLOTS, Hkv, G, dh)), jnp.bfloat16)
     qpos = jnp.asarray(lengths - 1, jnp.int32)[:, None]
-    args = (q, k, None, v, None, kpos, table, qpos)
+    args = (q, stack(k), None, stack(v), None, stack(kpos), table, qpos, jnp.int32(1))
     got = paged_decode_attention(*args, scale=scale, interpret=False)
     with jax.default_matmul_precision("highest"):
         want = ref(paged_decode_attention_ref, *args, scale=scale)

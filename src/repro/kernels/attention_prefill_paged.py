@@ -57,6 +57,8 @@ from repro.kernels.attention_paged import (
     _soft_cap,
     gather_pages,
     online_softmax_update,
+    page_heads,
+    split_heads,
 )
 
 # The last grid step holds a [Hkv, C*G, C] f32 score tile next to the
@@ -65,7 +67,8 @@ from repro.kernels.attention_paged import (
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def _prefill_kernel(start_ref, table_ref, *refs, scale, causal, window, softcap, nt, quantized):
+def _prefill_kernel(start_ref, table_ref, *refs, scale, causal, window, softcap, nt, n_kv,
+                    quantized):
     """Grid (B, nt + 1); steps 0..nt-1 stream pool pages via the prefetched
     table, step nt processes the chunk's in-flight K/V and finalizes."""
     del table_ref  # consumed by the index maps
@@ -100,11 +103,11 @@ def _prefill_kernel(start_ref, table_ref, *refs, scale, causal, window, softcap,
 
     @pl.when(it < nt)
     def _page_tile():
-        k = kq_ref[0].astype(jnp.float32)  # [Hkv, ps, dh]
-        v = vq_ref[0].astype(jnp.float32)
+        k = page_heads(kq_ref[0], n_kv)  # [ps, Hkv*dh] -> [Hkv, ps, dh]
+        v = page_heads(vq_ref[0], n_kv)
         if quantized:  # dequantize the page in VMEM
-            k = k * ks_ref[0]
-            v = v * vs_ref[0]
+            k = k * page_heads(ks_ref[0], n_kv)  # [Hkv, ps, 1]
+            v = v * page_heads(vs_ref[0], n_kv)
         # pool history is STRICTLY pre-chunk: when a shared-prefix admission
         # recomputes the prefix (rebuilding window-ring/SSM state), those
         # positions are live in shared pages AND in flight — mask the pool
@@ -126,10 +129,10 @@ def _prefill_kernel(start_ref, table_ref, *refs, scale, causal, window, softcap,
 )
 def paged_prefill_attention(
     q: jax.Array,       # [B, C, Hkv, G, dh] — one chunk of prompt queries per row
-    kq: jax.Array,      # [Pt, Hkv, ps, dh] page pool (int8 if quantized, else fp)
-    ks,                 # [Pt, Hkv, ps, 1] f32 scales, or None (fp pool)
-    vq: jax.Array,      # [Pt, Hkv, ps, dh]
-    vs,                 # [Pt, Hkv, ps, 1] or None
+    kq: jax.Array,      # [Pt, ps, Hkv*dh] page pool (int8 if quantized, else fp)
+    ks,                 # [Pt, ps, Hkv] f32 scales, or None (fp pool)
+    vq: jax.Array,      # [Pt, ps, Hkv*dh]
+    vs,                 # [Pt, ps, Hkv] or None
     kpos: jax.Array,    # [Pt, ps] int32 — absolute position per pool entry, -1 empty
     tables: jax.Array,  # [B, nt] int32 — each row's page ids; pre-clamped: -1 -> Pt-1
     qpos: jax.Array,    # [B, C] int32 — the chunk tokens' absolute positions, -1 invalid
@@ -146,7 +149,7 @@ def paged_prefill_attention(
     pool history is masked at positions >= its own chunk start
     (``qpos[:, 0]``; a row whose chunk starts at -1 sees no history)."""
     B, C, Hkv, G, dh = q.shape
-    Pt, _, ps, _ = kq.shape
+    Pt, ps, _ = kq.shape
     nt = tables.shape[1]
     CG = C * G
     quantized = ks is not None
@@ -163,9 +166,9 @@ def paged_prefill_attention(
     kern = functools.partial(
         _prefill_kernel,
         scale=scale, causal=causal, window=window, softcap=softcap,
-        nt=nt, quantized=quantized,
+        nt=nt, n_kv=Hkv, quantized=quantized,
     )
-    page = lambda b, t, st, tref: (tref[b, t], 0, 0, 0)
+    page = lambda b, t, st, tref: (tref[b, t], 0, 0)
     row4 = lambda b, t, st, tref: (b, 0, 0, 0)
     row3 = lambda b, t, st, tref: (b, 0, 0)
     in_specs = [
@@ -175,12 +178,12 @@ def paged_prefill_attention(
     ]
     args = [qh, q_col, q_row]
     for pool, scales in ((kq, ks), (vq, vs)):
-        in_specs.append(pl.BlockSpec((1, Hkv, ps, dh), page))        # page
+        in_specs.append(pl.BlockSpec((1, ps, Hkv * dh), page))       # page
         args.append(pool)
         if quantized:
-            in_specs.append(pl.BlockSpec((1, Hkv, ps, 1), page))     # scales
+            in_specs.append(pl.BlockSpec((1, ps, Hkv), page))        # scales
             args.append(scales)
-    in_specs.append(pl.BlockSpec((1, 1, ps), lambda b, t, st, tref: (tref[b, t], 0, 0)))
+    in_specs.append(pl.BlockSpec((1, 1, ps), page))
     args.append(kpos.reshape(Pt, 1, ps))                             # pool positions
     for chunk in (ck, cv):
         in_specs.append(pl.BlockSpec((1, Hkv, C, dh), row4))         # in-flight K/V
@@ -215,12 +218,14 @@ def paged_prefill_attention_ref(
     """Pure-jnp oracle: gather each row's mapped pages into a contiguous
     history, append the chunk's in-flight K/V, masked f32 softmax over the
     union."""
+    Hkv = q.shape[2]
     gather = lambda pool: gather_pages(pool, tables)  # pre-clamped tables
-    k = gather(kq).astype(jnp.float32)
-    v = gather(vq).astype(jnp.float32)
+    heads = lambda pool: split_heads(gather(pool), Hkv).astype(jnp.float32)
+    k = heads(kq)  # [B, T, Hkv, dh]
+    v = heads(vq)
     if ks is not None:
-        k = k * gather(ks)
-        v = v * gather(vs)
+        k = k * heads(ks)
+        v = v * heads(vs)
     k = jnp.concatenate([k, ck.astype(jnp.float32)], axis=1)  # [B, T, Hkv, dh]
     v = jnp.concatenate([v, cv.astype(jnp.float32)], axis=1)
     qpos = qpos.astype(jnp.int32)

@@ -67,15 +67,17 @@ def fused_decode_attention_quant(q, kq, ks, vq, vs, kpos, qpos, *, scale, causal
     )
 
 
-def fused_decode_attention_paged(q, kq, ks, vq, vs, kpos, table, qpos, *, scale, causal, window, softcap):
-    """Decode attention over a paged KV pool: pages gathered via the
-    scalar-prefetched block table inside the kernel, int8 pages dequantized
-    in VMEM when ``ks``/``vs`` scales are given (kernels/attention_paged.py).
-    ``table`` must be pre-clamped (-1 entries -> trash page)."""
+def fused_decode_attention_paged(q, kq, ks, vq, vs, kpos, table, qpos, layer, *, scale, causal,
+                                 window, softcap):
+    """Decode attention over layer ``layer`` of stacked paged KV pools: pages
+    gathered via the scalar-prefetched block table and layer index inside
+    the kernel, int8 pages dequantized in VMEM when ``ks``/``vs`` scales are
+    given (kernels/attention_paged.py).  ``table`` must be pre-clamped (-1
+    entries -> trash page)."""
     from repro.kernels.attention_paged import paged_decode_attention
 
     return paged_decode_attention(
-        q, kq, ks, vq, vs, kpos, table, qpos,
+        q, kq, ks, vq, vs, kpos, table, qpos, layer,
         scale=scale, causal=causal, window=window, softcap=softcap,
         interpret=_interpret(),
     )

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttnSpec, ModelConfig
-from repro.kernels.attention_paged import gather_pages
+from repro.kernels.attention_paged import gather_pages, page_rows, split_heads
 from repro.models.modules import apply_rope, dense_init, init_rmsnorm, rmsnorm, softcap
 from repro.parallel.sharding import shard_hint
 from repro.quant.kv import QuantizedKV, kv_quantize_values, materialize_kv
@@ -93,9 +93,10 @@ def spec_is_paged(spec: AttnSpec) -> bool:
 
 
 def init_paged_kv_cache(n_pages: int, page_size: int, n_kv: int, head_dim: int, dtype, *, kv_bits: int = 0) -> dict:
-    """Shared page-pool KV cache: ``[n_pages + 1, n_kv, page_size, head_dim]``
-    with NO batch axis — sequences own pages through per-slot block tables
-    (serving/kv_pool.py) instead of reserving a contiguous capacity row.
+    """Shared page-pool KV cache: K and V ``[n_pages + 1, page_size,
+    n_kv * head_dim]`` with NO batch axis — sequences own pages through
+    per-slot block tables (serving/kv_pool.py) instead of reserving a
+    contiguous capacity row.
 
     The extra last page is the *trash* page: never handed out by the
     allocator, its ``pos`` stays -1 forever.  Unmapped (-1) block-table
@@ -105,16 +106,19 @@ def init_paged_kv_cache(n_pages: int, page_size: int, n_kv: int, head_dim: int, 
     per-row masking of the pool.
 
     ``kv_bits=8`` stores pages as int8 :class:`~repro.quant.kv.QuantizedKV`
-    — the two serving memory levers compose: ~4x fewer bytes per cache
-    token × fragmentation-free packing of those tokens.
+    with per-(token, head) scales ``[n_pages + 1, page_size, n_kv]`` — the
+    two serving memory levers compose: ~4x fewer bytes per cache token ×
+    fragmentation-free packing of those tokens.
 
-    Heads lead the page so one page of every head is one contiguous block
-    whose trailing ``(page_size, head_dim)`` dims are what the Pallas page
-    kernels' BlockSpecs tile (kernels/attention_paged.py)."""
-    shape = (n_pages + 1, n_kv, page_size, head_dim)
+    A page is ``page_size`` token rows, each row one token's K (or V) for
+    every head side by side: trailing dims the TPU tiles without padding, so
+    the device keeps the pool page-major and the Pallas page kernels
+    (kernels/attention_paged.py) read its pages where they lie."""
+    shape = (n_pages + 1, page_size, n_kv * head_dim)
     if kv_bits == 8:
-        k = QuantizedKV.zeros(shape, dtype)
-        v = QuantizedKV.zeros(shape, dtype)
+        k, v = (QuantizedKV(jnp.zeros(shape, jnp.int8),
+                            jnp.zeros(shape[:-1] + (n_kv,), jnp.float32), str(jnp.dtype(dtype)))
+                for _ in range(2))
     elif kv_bits == 0:
         k = jnp.zeros(shape, dtype)
         v = jnp.zeros(shape, dtype)
@@ -272,22 +276,25 @@ def _paged_clamp_table(table: jax.Array, n_pages_total: int) -> jax.Array:
     return jnp.where(table < 0, n_pages_total - 1, table).astype(jnp.int32)
 
 
-def _paged_cache_write_decode(cache: dict, k_new, v_new, row_pos, table) -> dict:
-    """Write one token per row into its block-table page.  Rows whose table
-    entry for ``row_pos // page_size`` is unmapped (inactive slots, whose
-    table rows the scheduler resets to -1) land in the trash page with a -1
-    position — self-masking, so no post-hoc merge of the pool is needed."""
-    Pt, ps = cache["pos"].shape
+def _paged_cache_write_decode(cache: dict, k_new, v_new, row_pos, table, layer) -> dict:
+    """Write one token per row into its block-table page of layer ``layer``
+    of the stacked pools (``[layers, Pt, ...]`` leaves): B entries scattered
+    at ``[layer, page, :, offset]``, in place when the stack is a donated
+    loop carry.  Rows whose table entry for ``row_pos // page_size`` is
+    unmapped (inactive slots, whose table rows the scheduler resets to -1)
+    land in the trash page with a -1 position — self-masking, so no
+    post-hoc merge of the pool is needed."""
+    _, Pt, ps = cache["pos"].shape
     B = row_pos.shape[0]
     rows = jnp.arange(B)
     entry = row_pos.astype(jnp.int32) // ps
     offs = row_pos.astype(jnp.int32) % ps
     pages = _paged_clamp_table(table[rows, entry], Pt)
-    write = lambda buf, vals: buf.at[pages, :, offs].set(vals[:, 0])
+    write = lambda buf, vals: buf.at[layer, pages, offs].set(page_rows(vals[:, 0]))
     k = _write_kv(cache["k"], k_new, write)
     v = _write_kv(cache["v"], v_new, write)
     pos_val = jnp.where(pages == Pt - 1, -1, row_pos.astype(jnp.int32))
-    pos = cache["pos"].at[pages, offs].set(pos_val)
+    pos = cache["pos"].at[layer, pages, offs].set(pos_val)
     return {"k": k, "v": v, "pos": pos}
 
 
@@ -314,7 +321,7 @@ def _paged_cache_write_chunk(cache: dict, k_new, v_new, positions, table_row) ->
     pages = _paged_clamp_table(table_row[entry], Pt)
     already = cache["pos"][pages, offs] == pos  # shared-prefix entries
     pages = jnp.where(already, Pt - 1, pages)
-    write = lambda buf, vals: buf.at[pages, :, offs].set(vals[0])
+    write = lambda buf, vals: buf.at[pages, offs].set(page_rows(vals[0]))
     k = _write_kv(cache["k"], k_new, write)
     v = _write_kv(cache["v"], v_new, write)
     pos_val = jnp.where(pages == Pt - 1, -1, pos)
@@ -343,9 +350,7 @@ def _paged_cache_write_chunk_batched(cache: dict, k_new, v_new, positions, table
     pages = jnp.where(already | ~valid, Pt - 1, pages)
     flat_p = pages.reshape(-1)
     flat_o = offs.reshape(-1)
-    write = lambda buf, vals: buf.at[flat_p, :, flat_o].set(
-        vals.reshape((B * C,) + vals.shape[2:])
-    )
+    write = lambda buf, vals: buf.at[flat_p, flat_o].set(page_rows(vals).reshape(B * C, -1))
     k = _write_kv(cache["k"], k_new, write)
     v = _write_kv(cache["v"], v_new, write)
     # every trash-page write carries -1, so colliding invalid entries are
@@ -398,18 +403,15 @@ def _paged_prefill_chunk_attend_batched(q, k, v, cache: dict, positions, tables,
         y = _over_slots(kernel, (q.reshape(B, C, Hkv, H // Hkv, dh), tbl, positions, k, v),
                         pool + (cache["pos"],))
         return y.reshape(B, C, H, dh)
+    heads = lambda pool: split_heads(gather_pages(pool, tbl), Hkv)
     if quant:
         kh = materialize_kv(QuantizedKV(
-            gather_pages(cache["k"].q, tbl), gather_pages(cache["k"].scale, tbl),
-            cache["k"].orig_dtype,
-        ))
+            heads(cache["k"].q), heads(cache["k"].scale), cache["k"].orig_dtype))
         vh = materialize_kv(QuantizedKV(
-            gather_pages(cache["v"].q, tbl), gather_pages(cache["v"].scale, tbl),
-            cache["v"].orig_dtype,
-        ))
+            heads(cache["v"].q), heads(cache["v"].scale), cache["v"].orig_dtype))
     else:
-        kh = gather_pages(cache["k"], tbl)
-        vh = gather_pages(cache["v"], tbl)
+        kh = heads(cache["k"])
+        vh = heads(cache["v"])
     kcat = jnp.concatenate([kh.astype(k.dtype), k], axis=1)
     vcat = jnp.concatenate([vh.astype(v.dtype), v], axis=1)
     hist_pos = gather_pages(cache["pos"], tbl)  # [B, nt*ps]
@@ -462,20 +464,21 @@ def _cache_write_chunk_batched(cache: dict, k, v, positions) -> dict:
     return {"k": k_, "v": v_, "pos": pos_}
 
 
-def _paged_decode_attend(q, cache: dict, row_pos, table, spec: AttnSpec, scale: float):
-    """One-token decode over a paged pool.  q: [B, 1, H, dh]."""
+def _paged_decode_attend(q, cache: dict, row_pos, table, layer, spec: AttnSpec, scale: float):
+    """One-token decode over layer ``layer`` of the stacked paged pools
+    (``[layers, Pt, ...]`` leaves, read in place).  q: [B, 1, H, dh]."""
     mode = PAGED_BACKEND[0]
     if mode is None:
         mode = "kernel" if jax.default_backend() == "tpu" else "ref"
     window = spec.window if spec.kind == "local" else 0
-    Pt = cache["pos"].shape[0]
+    Pt = cache["pos"].shape[1]
     tbl = _paged_clamp_table(table, Pt)
     quant = isinstance(cache["k"], QuantizedKV)
+    B, S, H, dh = q.shape
+    Hkv = cache["k"].shape[-1] // dh  # pool leaf: [layers, Pt, ps, Hkv*dh]
     if mode == "kernel":
         from repro.kernels.ops import fused_decode_attention_paged
 
-        B, S, H, dh = q.shape
-        Hkv = cache["k"].shape[1]  # pool leaf: [Pt, Hkv, ps, dh]
         qg = q[:, 0].reshape(B, Hkv, H // Hkv, dh)
         if quant:
             pool = (cache["k"].q, cache["k"].scale, cache["v"].q, cache["v"].scale)
@@ -484,28 +487,25 @@ def _paged_decode_attend(q, cache: dict, row_pos, table, spec: AttnSpec, scale: 
 
         def kernel(rows, pool):
             qg, tb, qp = rows
-            kq, ks, vq, vs, kpos = pool
+            kq, ks, vq, vs, kpos, layer = pool
             return fused_decode_attention_paged(
-                qg, kq, ks, vq, vs, kpos, tb, qp,
+                qg, kq, ks, vq, vs, kpos, tb, qp, layer,
                 scale=scale, causal=spec.causal, window=window,
                 softcap=spec.logit_softcap,
             )
 
-        y = _over_slots(kernel, (qg, tbl, row_pos[:, None]), pool + (cache["pos"],))
+        y = _over_slots(kernel, (qg, tbl, row_pos[:, None]), pool + (cache["pos"], layer))
         return y.reshape(B, 1, H, dh)
+    heads = lambda pool: split_heads(gather_pages(pool, tbl, layer), Hkv)
     if quant:
         k = materialize_kv(QuantizedKV(
-            gather_pages(cache["k"].q, tbl), gather_pages(cache["k"].scale, tbl),
-            cache["k"].orig_dtype,
-        ))
+            heads(cache["k"].q), heads(cache["k"].scale), cache["k"].orig_dtype))
         v = materialize_kv(QuantizedKV(
-            gather_pages(cache["v"].q, tbl), gather_pages(cache["v"].scale, tbl),
-            cache["v"].orig_dtype,
-        ))
+            heads(cache["v"].q), heads(cache["v"].scale), cache["v"].orig_dtype))
     else:
-        k = gather_pages(cache["k"], tbl)
-        v = gather_pages(cache["v"], tbl)
-    k_pos = gather_pages(cache["pos"], tbl)
+        k = heads(cache["k"])
+        v = heads(cache["v"])
+    k_pos = gather_pages(cache["pos"], tbl, layer)
     mask = _window_causal_mask(row_pos[:, None], k_pos, window, spec.causal)
     return _sdpa(q, k, v, mask, scale, spec.logit_softcap)
 
@@ -607,6 +607,7 @@ def attention(
     cache: Optional[dict] = None,
     mode: str = "train",
     block_table: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ):
     """Returns (y, new_cache).  mode: train | prefill | decode.
 
@@ -615,7 +616,10 @@ def attention(
     - decode:  x is [B, 1, d]; reads cache, writes the new token into it.
     - decode_paged: like decode_ragged, but global-context caches are shared
       page pools addressed through ``block_table`` [B, max_pages] (window
-      layers keep their per-slot rings; see ``spec_is_paged``).
+      layers keep their per-slot rings; see ``spec_is_paged``).  A paged
+      layer's ``cache`` is its segment position's whole stack of pools
+      (``[layers, Pt, ...]`` leaves) and ``layer`` the index of this layer
+      in it: the token is written and the pages read in place.
     - prefill_chunk: one page-aligned chunk of a resumable admission prefill
       (x is [1, C, d], positions are absolute).  Paged layers attend over
       (already-written pool pages ++ in-flight chunk K/V) and write the chunk
@@ -730,10 +734,11 @@ def attention(
         row_pos = positions[:, 0] if positions.ndim == 2 else positions
         row_pos = jnp.broadcast_to(row_pos, (B,)).astype(jnp.int32)
         if mode == "decode_paged" and spec_is_paged(spec):
-            assert block_table is not None, "decode_paged needs a block table"
+            assert block_table is not None and layer is not None, \
+                "decode_paged needs a block table and the layer index"
             with jax.named_scope("kv_write"):
-                new_cache = _paged_cache_write_decode(cache, k, v, row_pos, block_table)
-            y = _paged_decode_attend(q, new_cache, row_pos, block_table, spec, scale)
+                new_cache = _paged_cache_write_decode(cache, k, v, row_pos, block_table, layer)
+            y = _paged_decode_attend(q, new_cache, row_pos, block_table, layer, spec, scale)
         else:
             idx = row_pos if mode in ("decode_ragged", "decode_paged") else row_pos[0]
             new_cache = _cache_write_decode(cache, k, v, idx)

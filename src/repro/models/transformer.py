@@ -99,6 +99,7 @@ def apply_layer(
     mode: str = "train",
     memory: Optional[jax.Array] = None,
     block_table: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     collect_stats: bool = False,
     count_routed: bool = False,
 ) -> Tuple:
@@ -107,7 +108,9 @@ def apply_layer(
     for non-MoE layers): ``"stats"``, its jit-returnable ``RoutingStats``,
     and ``"routed"``, the int32 count of experts its dispatch gave rows.
     The flags are static (part of the trace), so telemetry collection is
-    decided when the caller builds its jitted step."""
+    decided when the caller builds its jitted step.  ``layer`` (paged
+    decode): the index of this layer in its stacked page pools, which
+    ``cache["self"]`` then holds whole."""
     aux = jnp.zeros((), jnp.float32)
     tel = None
     h = rmsnorm(params["norm_mixer"], x, cfg.rms_eps)
@@ -117,7 +120,7 @@ def apply_layer(
         with jax.named_scope("attn"):
             y, new_self = attention(
                 cfg, m, params["attn"], h, positions, cache=sc, mode=mode,
-                block_table=block_table,
+                block_table=block_table, layer=layer,
             )
     elif isinstance(m, SSMSpec):
         y, new_self = ssm_layer(
@@ -215,21 +218,35 @@ def apply_segment(
     out of the scan's ys stacking for free).
 
     ``block_table`` (paged decode) is layer-invariant: every layer's page
-    pool shares one table, so it rides into the scan body as a capture."""
+    pool shares one table, so it rides into the scan body as a capture.
+
+    In ``decode_paged`` mode each paged position's ``self`` pools ride in
+    the scan's *carry*, whole (``[repeats, Pt, ...]``), and the body gets
+    the layer's index from ``xs``: the layer writes its tokens and reads
+    its pages at that index in place, so no layer's pool is sliced out of
+    the stack or written back into a stacked output.  Per-slot leaves
+    (window rings, SSM/LRU states, cross caches) stay in ``xs``/``ys``."""
     has_cache = caches is not None
     collect = collect_stats or count_routed
+    carried = [f"pos{j}" for j, ls in enumerate(seg.pattern)
+               if has_cache and mode == "decode_paged"
+               and isinstance(ls.mixer, AttnSpec) and spec_is_paged(ls.mixer)]
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux, pools = carry
+        layer_params, per_layer, layer = xs
         new_caches = {}
+        new_pools = {}
         tel_out = {}
         for j, ls in enumerate(seg.pattern):
             pkey = f"pos{j}"
-            c = xs[1][pkey] if has_cache else None
+            c = per_layer[pkey] if has_cache else None
+            if pkey in pools:
+                c = {**c, "self": pools[pkey]}
             out = apply_layer(
-                cfg, ls, xs[0][pkey], x, positions, cache=c, mode=mode, memory=memory,
-                block_table=block_table, collect_stats=collect_stats,
-                count_routed=count_routed,
+                cfg, ls, layer_params[pkey], x, positions, cache=c, mode=mode, memory=memory,
+                block_table=block_table, layer=layer if pkey in pools else None,
+                collect_stats=collect_stats, count_routed=count_routed,
             )
             if collect:
                 x, c_new, a, tel = out
@@ -237,26 +254,33 @@ def apply_segment(
                     tel_out[pkey] = tel
             else:
                 x, c_new, a = out
+            if pkey in pools:
+                c_new = dict(c_new)
+                new_pools[pkey] = c_new.pop("self")
             if has_cache:
                 new_caches[pkey] = c_new
             aux = aux + a
         ys = new_caches if has_cache else 0
         if collect:
             ys = (ys, tel_out)
-        return (x, aux), ys
+        return (x, aux, new_pools), ys
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
+    pools = {pk: caches[pk]["self"] for pk in carried}
+    per_layer = caches and {
+        pk: {k: v for k, v in c.items() if not (pk in pools and k == "self")}
+        for pk, c in caches.items()}
     # scan requires every xs leaf to have leading dim == repeats
-    xs = (params, caches if has_cache else jnp.zeros((seg.repeats,), jnp.int8))
-    (x, aux), ys = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs, unroll=_SCAN_UNROLL[0] or 1
+    xs = (params, per_layer, jnp.arange(seg.repeats, dtype=jnp.int32))
+    (x, aux, pools), ys = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), pools), xs, unroll=_SCAN_UNROLL[0] or 1
     )
-    if collect:
-        ys, tel = ys
-        return x, (ys if has_cache else None), aux, tel
-    return x, (ys if has_cache else None), aux
+    ys, tel = ys if collect else (ys, None)
+    new_caches = {pk: ({**c, "self": pools[pk]} if pk in pools else c)
+                  for pk, c in ys.items()} if has_cache else None
+    return (x, new_caches, aux, tel) if collect else (x, new_caches, aux)
 
 
 # Dry-run knob: setting _SCAN_UNROLL[0] = True fully unrolls segment scans so

@@ -23,8 +23,9 @@ Layout (one cache tensor, e.g. K):
   * ``scale``  f32   [..., T, H_kv, 1]    — amax/127 per (timestep, head)
 
 The leading dims are layout-agnostic: contiguous slot caches carry
-``[..., B, T, ...]`` and the paged serving path lays the same pair out *per
-page* as ``[..., n_pages + 1, page_size, ...]`` (models/attention.py
+``[..., B, T, ...]``, and the paged serving path lays the same pair out *per
+page* as token rows, ``q [..., n_pages + 1, page_size, H_kv*dh]`` and
+``scale [..., n_pages + 1, page_size, H_kv]`` (models/attention.py
 ``init_paged_kv_cache``) — per-token scales mean pages quantize, scatter,
 recycle, and gather through block tables with no rescaling anywhere, and
 the dequant-in-VMEM kernels stream 1-byte entries either way.

@@ -70,8 +70,9 @@ def _toy_chunk(quantized, n_hist=6):
     key = jax.random.PRNGKey(0)
     C, Hkv, G, dh, ps, Pt = 5, 2, 2, 8, 4, 10
     q = jax.random.normal(key, (C, Hkv, G, dh), jnp.float32)
-    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, Hkv, ps, dh), jnp.float32)
-    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, Hkv, ps, dh), jnp.float32)
+    # pool pages are token rows, every head side by side: [Pt, ps, Hkv*dh]
+    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, ps, Hkv * dh), jnp.float32)
+    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, ps, Hkv * dh), jnp.float32)
     ck = jax.random.normal(jax.random.fold_in(key, 3), (C, Hkv, dh), jnp.float32)
     cv = jax.random.normal(jax.random.fold_in(key, 4), (C, Hkv, dh), jnp.float32)
     kpos = np.full((Pt, ps), -1, np.int32)
@@ -83,8 +84,9 @@ def _toy_chunk(quantized, n_hist=6):
     if quantized:
         from repro.quant.kv import kv_quantize_values
 
-        kq, ks = kv_quantize_values(kf)
-        vq, vs = kv_quantize_values(vf)
+        rows = lambda a: a.reshape(Pt, ps, -1)
+        kq, ks = map(rows, kv_quantize_values(kf.reshape(Pt, ps, Hkv, dh)))
+        vq, vs = map(rows, kv_quantize_values(vf.reshape(Pt, ps, Hkv, dh)))
     else:
         kq, ks, vq, vs = kf, None, vf, None
     return q, kq, ks, vq, vs, jnp.asarray(kpos), jnp.asarray(table), qpos, ck, cv
@@ -130,7 +132,7 @@ class TestPrefillKernel:
         )
 
         q, kq, ks, vq, vs, _, table, _, ck, cv = _toy_chunk(False)
-        kpos = jnp.full((kq.shape[0], kq.shape[2]), -1, jnp.int32)  # [Pt, ps]
+        kpos = jnp.full(kq.shape[:2], -1, jnp.int32)  # [Pt, ps]
         qpos = jnp.arange(q.shape[0], dtype=jnp.int32)
         args = _rows(q, kq, ks, vq, vs, kpos, table, qpos, ck, cv)
         out_k = paged_prefill_attention(*args, scale=0.3, interpret=True)

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.base import Segment
 from repro.configs.registry import all_configs, make_reduced
 from repro.models.model import (
     init_caches,
@@ -102,29 +103,45 @@ class TestBlockTables:
 # ---------------------------------------------------------------------------
 
 
-def _toy_pool(quantized):
+N_LAYERS = 3  # pools stacked per segment position: [layers, Pt, ...]
+
+
+def _toy_pool(quantized, layer=1):
+    """A stack of ``N_LAYERS`` page pools with different contents per layer,
+    and the kernel's arguments reading layer ``layer`` of it."""
     key = jax.random.PRNGKey(0)
     B, Hkv, G, dh, ps, nt, Pt = 3, 2, 2, 8, 4, 5, 12  # Pt-1 = trash page
+    L = N_LAYERS
     q = jax.random.normal(key, (B, Hkv, G, dh), jnp.float32)
-    kf = jax.random.normal(jax.random.fold_in(key, 1), (Pt, Hkv, ps, dh), jnp.float32)
-    vf = jax.random.normal(jax.random.fold_in(key, 2), (Pt, Hkv, ps, dh), jnp.float32)
-    kpos = np.full((Pt, ps), -1, np.int32)
+    # pool pages are token rows, every head side by side: [L, Pt, ps, Hkv*dh]
+    kf = jax.random.normal(jax.random.fold_in(key, 1), (L, Pt, ps, Hkv * dh), jnp.float32)
+    vf = jax.random.normal(jax.random.fold_in(key, 2), (L, Pt, ps, Hkv * dh), jnp.float32)
+    kpos = np.full((L, Pt, ps), -1, np.int32)
     tables = np.full((B, nt), -1, np.int32)
     seqs = {0: ([3, 7, 0], 10), 1: ([5, 9], 6), 2: ([1], 2)}
     for b, (pages, n) in seqs.items():
         tables[b, : len(pages)] = pages
         for t in range(n):
-            kpos[pages[t // ps], t % ps] = t
+            kpos[:, pages[t // ps], t % ps] = t
     tbl = jnp.asarray(np.where(tables < 0, Pt - 1, tables), jnp.int32)
     qpos = jnp.asarray([[seqs[b][1] - 1] for b in range(B)], jnp.int32)
     if quantized:
         from repro.quant.kv import kv_quantize_values
 
-        kq, ks = kv_quantize_values(kf)
-        vq, vs = kv_quantize_values(vf)
+        rows = lambda a: a.reshape(L, Pt, ps, -1)
+        kq, ks = map(rows, kv_quantize_values(kf.reshape(L, Pt, ps, Hkv, dh)))
+        vq, vs = map(rows, kv_quantize_values(vf.reshape(L, Pt, ps, Hkv, dh)))
     else:
         kq, ks, vq, vs = kf, None, vf, None
-    return q, kq, ks, vq, vs, jnp.asarray(kpos), tbl, qpos
+    return q, kq, ks, vq, vs, jnp.asarray(kpos), tbl, qpos, jnp.asarray(layer, jnp.int32)
+
+
+def _one_layer(args, layer):
+    """The same call on layer ``layer``'s pool alone: a stack of one."""
+    pick = lambda a: None if a is None else a[layer:layer + 1]
+    q, kq, ks, vq, vs, kpos, tbl, qpos, _ = args
+    return (q, pick(kq), pick(ks), pick(vq), pick(vs), pick(kpos), tbl, qpos,
+            jnp.asarray(0, jnp.int32))
 
 
 class TestPagedKernel:
@@ -157,14 +174,40 @@ class TestPagedKernel:
         entries (clamped to the trash page) are fully masked."""
         from repro.kernels.attention_paged import paged_decode_attention_ref
 
-        q, kq, ks, vq, vs, kpos, tbl, qpos = _toy_pool(False)
-        out = paged_decode_attention_ref(q, kq, ks, vq, vs, kpos, tbl, qpos, scale=0.35)
+        q, kq, ks, vq, vs, kpos, tbl, qpos, layer = _toy_pool(False)
+        out = paged_decode_attention_ref(q, kq, ks, vq, vs, kpos, tbl, qpos, layer, scale=0.35)
         # row 2 uses 1 page; widen its view to 5 (all trash beyond page 0)
         assert np.isfinite(np.asarray(out)).all()
         out2 = paged_decode_attention_ref(
-            q, kq, ks, vq, vs, kpos, tbl.at[2, 1:].set(kq.shape[0] - 1), qpos, scale=0.35
+            q, kq, ks, vq, vs, kpos, tbl.at[2, 1:].set(kq.shape[1] - 1), qpos, layer,
+            scale=0.35,
         )
         np.testing.assert_allclose(np.asarray(out[2]), np.asarray(out2[2]), atol=1e-6)
+
+    @pytest.mark.parametrize("layer", [0, N_LAYERS - 1], ids=["first", "last"])
+    @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+    def test_layer_indexed_reads_its_layer(self, quantized, layer):
+        """The kernel reads layer ``layer`` of the stacked pools in place:
+        it matches the ref on the stack, and equals the call on that layer's
+        pool alone, with and without a window and a softcap; other layers'
+        contents (which differ) change nothing."""
+        from repro.kernels.attention_paged import (
+            paged_decode_attention,
+            paged_decode_attention_ref,
+        )
+
+        args = _toy_pool(quantized, layer)
+        for kw in (dict(scale=0.35), dict(scale=0.35, window=3, softcap=5.0)):
+            out_k = np.asarray(paged_decode_attention(*args, interpret=True, **kw))
+            out_r = np.asarray(paged_decode_attention_ref(*args, **kw))
+            np.testing.assert_allclose(out_k, out_r, atol=1e-5)
+            alone = np.asarray(paged_decode_attention(*_one_layer(args, layer),
+                                                      interpret=True, **kw))
+            np.testing.assert_array_equal(out_k, alone)
+            other = (layer + 1) % N_LAYERS
+            out_o = np.asarray(paged_decode_attention(*args[:-1], jnp.asarray(other),
+                                                      interpret=True, **kw))
+            assert np.abs(out_o - out_k).max() > 1e-3  # the layers differ
 
 
 # ---------------------------------------------------------------------------
@@ -208,6 +251,96 @@ class TestPagedModelParity:
             cfg, params, toks, positions, active, paged, jnp.asarray(tables.table)
         )
         np.testing.assert_allclose(np.asarray(lg_c), np.asarray(lg_p), atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# The decode step writes the stacked pools in place
+# ---------------------------------------------------------------------------
+
+
+def _multi_repeat_cfg():
+    """Reduced PR-MoE (a dense and a MoE layer per pattern, two segments)
+    with 2 repeats per segment: each segment position's pools stack 2
+    layers, so the step's writes and reads go by layer index."""
+    cfg = make_reduced(all_configs()["nlg-350m-prmoe-32-64"])
+    return cfg.replace(segments=tuple(Segment(seg.pattern, 2) for seg in cfg.segments))
+
+
+class TestInPlaceDecodeWrite:
+    def test_step_writes_only_active_rows_entries(self):
+        """One decode step over stacked pools full of random contents: each
+        active row's K/V/pos lands at ``[layer, page, offset]`` of every
+        layer (K/V equal to what the contiguous step writes for that row and
+        position), the inactive row's writes land only in the trash page
+        with ``pos == -1``, and every other entry of every layer's pool is
+        bit-identical."""
+        cfg = _multi_repeat_cfg()
+        params = init_params(cfg, jax.random.PRNGKey(1))
+        ps, n_pages, slots, cap = 4, 12, 3, 16
+        Pt, nt = n_pages + 1, cap // ps
+        Hkv, dh = cfg.num_kv_heads, cfg.head_dim
+        rng = np.random.default_rng(0)
+        # row 0 holds 5 tokens (pages 3, 7), row 1 is inactive, row 2 holds 2 (page 1)
+        tables = np.full((slots, nt), -1, np.int32)
+        tables[0, :2], tables[2, :1] = [3, 7], [1]
+        lengths = {0: 5, 2: 2}  # the step writes each active row at position == length
+        paged = init_paged_caches(cfg, slots, cap, n_pages=n_pages, page_size=ps)
+        contig = init_caches(cfg, slots, cap)
+        for sk in paged:
+            for pk in paged[sk]:
+                R = paged[sk][pk]["self"]["k"].shape[0]
+                pool = {n: rng.standard_normal((R, Pt, ps, Hkv * dh)).astype(np.float32)
+                        for n in ("k", "v")}
+                pool["pos"] = np.full((R, Pt, ps), -1, np.int32)
+                ring = {n: np.zeros((R, slots, cap, Hkv, dh), np.float32) for n in ("k", "v")}
+                ring["pos"] = np.full((R, slots, cap), -1, np.int32)
+                for b, n in lengths.items():
+                    for t in range(n):
+                        page, off = tables[b, t // ps], t % ps
+                        pool["pos"][:, page, off] = ring["pos"][:, b, t] = t
+                        for name in ("k", "v"):
+                            ring[name][:, b, t] = pool[name][:, page, off].reshape(R, Hkv, dh)
+                paged[sk][pk]["self"] = {n: jnp.asarray(a) for n, a in pool.items()}
+                contig[sk][pk]["self"] = {n: jnp.asarray(a) for n, a in ring.items()}
+        toks = jnp.asarray([[3], [5], [7]], jnp.int32)
+        positions = jnp.asarray([lengths[0], 0, lengths[2]], jnp.int32)
+        active = jnp.asarray([True, False, True])
+        lg_p, new = jax.jit(lambda c: paged_ragged_decode_step(
+            cfg, params, toks, positions, active, c, jnp.asarray(tables)))(paged)
+        lg_c, cnew = ragged_decode_step(cfg, params, toks, positions, active, contig)
+        np.testing.assert_allclose(np.asarray(lg_p)[[0, 2]], np.asarray(lg_c)[[0, 2]], atol=2e-4)
+
+        written = {b: (tables[b, n // ps], n % ps) for b, n in lengths.items()}
+        for sk in paged:
+            for pk in paged[sk]:
+                old = {n: np.asarray(a) for n, a in paged[sk][pk]["self"].items()}
+                got = {n: np.asarray(a) for n, a in new[sk][pk]["self"].items()}
+                want = {n: np.asarray(a) for n, a in cnew[sk][pk]["self"].items()}
+                untouched = np.ones(old["pos"].shape, bool)  # [R, Pt, ps]
+                untouched[:, Pt - 1] = False  # the trash page
+                for b, (page, off) in written.items():
+                    untouched[:, page, off] = False
+                    n = lengths[b]
+                    np.testing.assert_array_equal(got["pos"][:, page, off], n)
+                    for name in ("k", "v"):
+                        np.testing.assert_allclose(
+                            got[name][:, page, off],
+                            want[name][:, b, n].reshape(-1, Hkv * dh), atol=1e-4)
+                for name in ("k", "v", "pos"):
+                    np.testing.assert_array_equal(got[name][untouched], old[name][untouched])
+                np.testing.assert_array_equal(got["pos"][:, Pt - 1], -1)
+
+    def test_multi_repeat_greedy_matches_contiguous(self):
+        """20 greedy decode ticks over the stacked pools of a 2-segment,
+        2-repeat model give the contiguous engine's tokens."""
+        cfg = _multi_repeat_cfg()
+        params = init_params(cfg, jax.random.PRNGKey(1))
+        prompts = [[1, 2, 3, 4, 5], [9, 8, 7], [4, 4, 2, 1, 6, 6]]
+        want, _ = _serve(cfg, params, prompts, 20, slots=3, capacity=32)
+        got, eng = _serve(cfg, params, prompts, 20, slots=3, capacity=32,
+                          paged=True, page_size=4, n_pages=24)
+        assert got == want, (got, want)
+        assert eng.pool.free_count == eng.n_pages
 
 
 # ---------------------------------------------------------------------------

@@ -71,12 +71,14 @@ def _assert_kernel(lowered, kernel):
         assert KERNELS[kernel].match(name), (name, KERNELS[kernel].pattern)
 
 
-def _pool(s, quantized):
-    """(kq, ks, vq, vs, kpos) shapes of one layer's page pool."""
+def _pool(s, quantized, layers=()):
+    """(kq, ks, vq, vs, kpos) shapes of one layer's page pool, or of a
+    stack of ``layers`` pools given ``layers=(n,)``."""
     kd = jnp.int8 if quantized else jnp.bfloat16
-    scales = s((PT, HKV, PAGE, 1), jnp.float32) if quantized else None
-    return (s((PT, HKV, PAGE, DH), kd), scales, s((PT, HKV, PAGE, DH), kd), scales,
-            s((PT, PAGE), jnp.int32))
+    lead = tuple(layers) + (PT, PAGE)
+    scales = s(lead + (HKV,), jnp.float32) if quantized else None
+    return (s(lead + (HKV * DH,), kd), scales, s(lead + (HKV * DH,), kd), scales,
+            s(lead, jnp.int32))
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -85,7 +87,8 @@ def test_paged_decode_compiles(one_chip, quantized):
 
     s = _sds(one_chip)
     q = s((SLOTS, HKV, H // HKV, DH), jnp.bfloat16)
-    args = (q, *_pool(s, quantized), s((SLOTS, NT), jnp.int32), s((SLOTS, 1), jnp.int32))
+    args = (q, *_pool(s, quantized, (3,)), s((SLOTS, NT), jnp.int32), s((SLOTS, 1), jnp.int32),
+            s((), jnp.int32))
     _assert_kernel(paged_decode_attention.lower(*args, scale=DH ** -0.5, interpret=False),
                    "paged_decode_attn")
 
@@ -146,16 +149,98 @@ def test_paged_decode_under_ep_mesh_compiles(topo, monkeypatch):
     mesh = make_mesh((4,), ("data",), devices=topo.devices[:4])
     rules = {**DEFAULT_RULES, "expert": "data", "batch": "data"}
     rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
-    kq, _, vq, _, kpos = _pool(_sds(rep), False)
+    kq, _, vq, _, kpos = _pool(_sds(rep), False, (2,))
     cache = {"k": kq, "v": vq, "pos": kpos}
 
-    def decode(q, cache, row_pos, table):
-        return attn._paged_decode_attend(q, cache, row_pos, table, AttnSpec(kind="global"),
-                                         DH ** -0.5)
+    def decode(q, cache, row_pos, table, layer):
+        return attn._paged_decode_attend(q, cache, row_pos, table, layer,
+                                         AttnSpec(kind="global"), DH ** -0.5)
 
     with use_mesh(mesh, rules):
         lowered = jax.jit(decode).lower(
             jax.ShapeDtypeStruct((SLOTS, 1, H, DH), jnp.bfloat16, sharding=rows), cache,
             jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=rows),
-            jax.ShapeDtypeStruct((SLOTS, NT), jnp.int32, sharding=rows))
+            jax.ShapeDtypeStruct((SLOTS, NT), jnp.int32, sharding=rows),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
     _assert_kernel(lowered, "paged_decode_attn")
+
+
+# An instruction of the compiled text: name, result shape, opcode.
+INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+)\[([\d,]*)\]\S*\s+([\w\-]+)\(',
+                   re.MULTILINE)
+PARAM = re.compile(r'^\s*%?[\w.\-]+\s*=\s*(\w+)\[([\d,]*)\]\S*\s+parameter\((\d+)\)', re.MULTILINE)
+
+
+def test_paged_decode_step_keeps_pool_in_place(one_chip, monkeypatch):
+    """The whole paged decode step of a 2-segment stacked model (PR-MoE
+    pattern at published widths, 2 repeats per segment) writes and reads
+    each layer's pages in the stacked pools where they lie: no copy,
+    dynamic-slice or dynamic-update-slice in the compiled program yields a
+    K/V pool, a layer's or a stack's (only one layer's small ``[Pt, ps]``
+    positions are sliced, for the kernel's position gather); every stacked
+    pool parameter is aliased to its output; no temporary is as large as a
+    layer's pool; and the kernel keeps the name the trace reduction
+    matches."""
+    import repro.core.moe as moe
+    import repro.kernels.ops as ops
+    from repro.core.prmoe import nlg_moe
+    from repro.models import attention as attn
+    from repro.models.model import init_paged_caches, init_params, paged_ragged_decode_step
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(attn, "PAGED_BACKEND", ["kernel"])
+    monkeypatch.setattr(moe, "GROUPED_EXPERT_BACKEND", ["kernel"])
+    cfg = nlg_moe("nlg-350m-prmoe-32-64-8l", 8, D, H, (32, 64), residual=True)
+    assert [seg.repeats for seg in cfg.segments] == [2, 2]
+    assert (cfg.num_kv_heads, cfg.head_dim) == (HKV, DH)
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    caches = place(jax.eval_shape(lambda: init_paged_caches(
+        cfg, SLOTS, CAPACITY, n_pages=PT - 1, page_size=PAGE)))
+
+    def step(params, tokens, positions, active, caches, tables):
+        return paged_ragged_decode_step(cfg, params, tokens, positions, active, caches, tables,
+                                        return_routed=True)
+
+    s = _sds(one_chip)
+    lowered = jax.jit(step, donate_argnums=(4,)).lower(
+        params, s((SLOTS, 1), jnp.int32), s((SLOTS,), jnp.int32), s((SLOTS,), jnp.bool_), caches,
+        s((SLOTS, NT), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    def elems(dims):
+        n = 1
+        for d in dims:
+            n *= d
+        return n
+
+    moved = []
+    for name, _, dims, op in INSTR.findall(text):
+        dims = [int(d) for d in dims.split(",") if d]
+        if op in ("copy", "dynamic-slice", "dynamic-update-slice") and PT in dims \
+                and elems(dims) > PT * PAGE:
+            moved.append(f"{op} {name} {dims}")
+    assert not moved, moved
+
+    header = text.splitlines()[0]
+    aliased = {int(p) for p in re.findall(r"\}: \((\d+), \{", header)}
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    pools = [int(n) for _, dims, n in PARAM.findall(entry)
+             if dims.split(",")[-3:] == [str(PT), str(PAGE), str(HKV * DH)]]
+    assert len(pools) == 2 * 4, pools  # K and V of 2 positions x 2 segments
+    assert set(pools) <= aliased, (pools, header[:400])
+
+    layer_pool_bytes = 2 * PT * PAGE * HKV * DH * 2  # K + V, bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.trace import KERNELS
+
+    names = CUSTOM_CALL.findall(text)
+    assert any(KERNELS["paged_decode_attn"].match(n) for n in names), names
+    assert all(any(p.match(n) for p in KERNELS.values()) for n in names), names
