@@ -202,14 +202,17 @@ def moe_layer(
     """Returns (y [B,S,D], aux_loss scalar); with ``with_stats=True`` a
     further element — a jit-returnable ``RoutingStats`` (token-count-
     independent shapes) for per-layer telemetry (docs/OBSERVABILITY.md);
-    with ``with_routed=True`` a last element — an int32 scalar, the number
-    of experts the dispatch gave at least one row (its kept rows per
-    expert; on the EP paths, as the shard_map's dispatch counted them),
-    which is how many experts' weights the layer reads."""
+    with ``with_routed=True`` a last element — a dict of the dispatch's
+    counters: ``"routed"``, an int32 scalar, the number of experts the
+    dispatch gave at least one row (its kept rows per expert; on the EP
+    paths, as the shard_map's dispatch counted them), which is how many
+    experts' weights the layer reads; and on the serving EP paths
+    ``"ep_rows"``, int32 [ep], the rows each EP rank's experts received."""
     impl = impl or cfg.moe_impl
     B, S, D = x.shape
     E, K = spec.num_experts, spec.top_k
     stats = None
+    rows_per_rank = None
 
     if impl in ("ep_serve", "ep_grouped"):
         # Serving EP (core/moe_serve.py) needs an active mesh whose 'expert'
@@ -226,7 +229,7 @@ def moe_layer(
                 impl = "einsum" if get_mesh() is not None else "dense"
 
     if impl in ("ep_serve", "ep_grouped"):
-        from repro.core.moe_serve import moe_layer_ep_serve
+        from repro.core.moe_serve import ep_rows, moe_layer_ep_serve
 
         if isinstance(params.get("wi"), QuantizedArray):
             # shard_map in_specs address raw arrays (same rule as "ep"); the
@@ -239,6 +242,8 @@ def moe_layer(
             )}
         kernel = "grouped" if impl == "ep_grouped" else "dense"
         y, aux, counts = moe_layer_ep_serve(cfg, spec, params, x, kernel=kernel)
+        if with_routed:
+            rows_per_rank = ep_rows(counts, E)
         if with_stats:
             # Router + gating re-run on the replicated token set outside
             # shard_map.  For the replicated-token schedules (decode,
@@ -319,5 +324,8 @@ def moe_layer(
     if with_stats:
         out += (stats,)
     if with_routed:
-        out += (jnp.sum(counts > 0).astype(jnp.int32),)
+        routed = {"routed": jnp.sum(counts > 0).astype(jnp.int32)}
+        if rows_per_rank is not None:
+            routed["ep_rows"] = rows_per_rank
+        out += (routed,)
     return out

@@ -53,6 +53,12 @@ from repro.core.gating import (
 )
 from repro.parallel.sharding import get_mesh, get_rules
 
+# Named scope of every cross-chip collective of a serving MoE layer (the
+# expert-output psum/all_gather, the all-to-alls both ways, the routed-count
+# psum and the aux pmeans): ``launch/scope_split.py`` attributes the
+# exchange's device time by it.
+EXCHANGE = "ep_exchange"
+
 
 def serve_ep_axes(num_experts: int) -> Optional[Tuple[str, ...]]:
     """EP mesh axes for serving, or None when the ambient mesh can't shard
@@ -77,6 +83,19 @@ def serve_ep_axes(num_experts: int) -> Optional[Tuple[str, ...]]:
     if ep <= 1 or num_experts % ep != 0:
         return None
     return tuple(axes)
+
+
+def ep_rows(counts: jax.Array, num_experts: int) -> jax.Array:
+    """[ep] int32: the rows each EP rank's experts received, from a
+    layer's global per-expert ``counts`` [E] (rank r owns experts
+    ``[r*E_loc, (r+1)*E_loc)``).  A sum over the counts the dispatch
+    already returned: no gating is re-run and no collective is added."""
+    mesh = get_mesh()
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    ep = 1
+    for a in serve_ep_axes(num_experts):
+        ep *= sizes[a]
+    return counts.reshape(ep, num_experts // ep).sum(axis=1).astype(jnp.int32)
 
 
 def _ep_rank(axes) -> jax.Array:
@@ -133,11 +152,13 @@ def _body_replicated_dense(cfg: ModelConfig, spec: FFNSpec, axes, x, router, wi,
     # exists on exactly one shard, so this is exact reconstruction — the
     # combine below then runs replicated on the same values and global
     # gating as the single-device engine (bitwise parity, drops included).
-    out = jax.lax.all_gather(out_loc, axes, axis=0, tiled=True)  # [E, cap, D]
+    with jax.named_scope(EXCHANGE):
+        out = jax.lax.all_gather(out_loc, axes, axis=0, tiled=True)  # [E, cap, D]
     y = combine_dense(out, g, cap, E).reshape(B, S, D)
 
     aux = load_balance_loss(g.probs, g.expert_idx, E)
-    aux = jax.lax.pmean(aux, axes)  # identical per shard; certifies replication
+    with jax.named_scope(EXCHANGE):
+        aux = jax.lax.pmean(aux, axes)  # identical per shard; certifies replication
     return y, aux, kept_counts(g, E)  # global gating: the same on every shard
 
 
@@ -177,7 +198,8 @@ def _body_replicated_grouped(cfg: ModelConfig, spec: FFNSpec, axes, x, router, w
     xg_loc = jnp.where(row_mine[:, None], xg, 0)
     yg_loc = grouped_experts_ffn(_ffn_params(wi, wg, wo, spec.act), xg_loc, te_loc, spec.act)
     yg_loc = jnp.where(row_mine[:, None], yg_loc.astype(jnp.float32), 0.0)
-    yg = jax.lax.psum(yg_loc, axes)  # [Ct, D] f32, exact (single contributor/row)
+    with jax.named_scope(EXCHANGE):
+        yg = jax.lax.psum(yg_loc, axes)  # [Ct, D] f32, exact (single contributor/row)
 
     # Replicated combine — moe_grouped's scatter-add on the reassembled
     # buffer (already f32, matching its accumulation discipline).
@@ -186,7 +208,8 @@ def _body_replicated_grouped(cfg: ModelConfig, spec: FFNSpec, axes, x, router, w
     y = y.astype(xs.dtype).reshape(B, S, D)
 
     aux = load_balance_loss(g.probs, g.expert_idx, E)
-    aux = jax.lax.pmean(aux, axes)
+    with jax.named_scope(EXCHANGE):
+        aux = jax.lax.pmean(aux, axes)
     return y, aux, layout.counts  # global layout: the same on every shard
 
 
@@ -213,28 +236,32 @@ def _body_a2a(cfg: ModelConfig, spec: FFNSpec, axes, x_loc, router, wi, wg, wo):
     g = top_k_gating(logits, K, cap)
     buf = dispatch_dense(x_loc, g, cap, E)  # [E, cap, D]
 
-    if len(axes) == 2:
-        # two-hop hierarchical exchange (Fig. 8): intra-host axis first,
-        # layout transform, then the inter-host hop.  Expert ids are laid
-        # out outer-major, matching _ep_rank's ordering.
-        recv = hierarchical_all_to_all(buf, axes[1], axes[0])
-    else:
-        recv = flat_all_to_all(buf, axes)
+    with jax.named_scope(EXCHANGE):
+        if len(axes) == 2:
+            # two-hop hierarchical exchange (Fig. 8): intra-host axis first,
+            # layout transform, then the inter-host hop.  Expert ids are laid
+            # out outer-major, matching _ep_rank's ordering.
+            recv = hierarchical_all_to_all(buf, axes[1], axes[0])
+        else:
+            recv = flat_all_to_all(buf, axes)
     # recv: [E_loc, ep*cap, D]
     out = experts_ffn(_ffn_params(wi, wg, wo, spec.act), recv, spec.act)
-    if len(axes) == 2:
-        back = hierarchical_all_to_all_back(out, axes[1], axes[0])
-    else:
-        back = flat_all_to_all_back(out, axes)
+    with jax.named_scope(EXCHANGE):
+        if len(axes) == 2:
+            back = hierarchical_all_to_all_back(out, axes[1], axes[0])
+        else:
+            back = flat_all_to_all_back(out, axes)
     y = combine_dense(back, g, cap, E)  # [T_loc, D]
 
     # global-batch aux: pmean the linear per-expert stats, then the product
     f, p = load_balance_stats(g.probs, g.expert_idx, E)
-    f = jax.lax.pmean(f, axes)
-    p = jax.lax.pmean(p, axes)
+    with jax.named_scope(EXCHANGE):
+        f = jax.lax.pmean(f, axes)
+        p = jax.lax.pmean(p, axes)
     aux = E * jnp.sum(f * p)
     # rows each expert receives: every shard's kept assignments, summed
-    counts = jax.lax.psum(kept_counts(g, E), axes)
+    with jax.named_scope(EXCHANGE):
+        counts = jax.lax.psum(kept_counts(g, E), axes)
     return y, aux, counts
 
 

@@ -36,9 +36,10 @@ import re
 from pathlib import Path
 from typing import Dict, List, Tuple
 
-# The model's named scopes (models/, core/, serving/sampling.py).
+# The model's named scopes (models/, core/, serving/sampling.py), and the
+# EP exchange's collectives (core/moe_serve.py).
 SCOPES = ("attn", "kv_write", "moe_gate", "moe_dispatch", "expert_mlp",
-          "moe_combine", "residual_mlp", "mlp", "logits", "sample")
+          "moe_combine", "residual_mlp", "mlp", "logits", "sample", "ep_exchange")
 NO_OP_NAME = "(no op_name)"
 NO_SCOPE = "(no scope)"
 # The engine's paged decode step, by the module name XLA gives it.

@@ -145,12 +145,15 @@ def _run_segments(cfg, params, x, positions, caches, mode, memory, remat,
     {pos{j}: RoutingStats[repeats, ...]}}`` for every MoE position — the
     per-layer routing telemetry tree (jit-returnable; host side aggregates
     via ``core.gating.summarize_routing``).  With ``count_routed=True`` a
-    last element: int32 ``[n_moe_layers]``, in layer order, the number of
-    experts each MoE layer's dispatch gave at least one row."""
+    last element, the dispatch's counters in layer order:
+    ``{"experts_routed": int32 [n_moe_layers]}``, the number of experts each
+    MoE layer's dispatch gave at least one row, and on the serving EP paths
+    ``"ep_rows"``, int32 ``[n_moe_layers, ep]``, the rows each EP rank's
+    experts received."""
     aux = jnp.zeros((), jnp.float32)
     new_caches = {}
     stats = {}
-    routed = []
+    routed, rows = [], []
     for i, seg in enumerate(cfg.segments):
         c = caches.get(f"seg{i}") if caches is not None else None
         out = apply_segment(
@@ -166,6 +169,9 @@ def _run_segments(cfg, params, x, positions, caches, mode, memory, remat,
             if count_routed and moe:
                 # [repeats, positions] -> layer order (repeat-major)
                 routed.append(jnp.stack([tel[pk]["routed"] for pk in moe], axis=1).reshape(-1))
+                if "ep_rows" in tel[moe[0]]:
+                    r = jnp.stack([tel[pk]["ep_rows"] for pk in moe], axis=1)
+                    rows.append(r.reshape(-1, r.shape[-1]))
         else:
             x, c_new, a = out
         aux = aux + a
@@ -175,7 +181,11 @@ def _run_segments(cfg, params, x, positions, caches, mode, memory, remat,
     if collect_stats:
         res += (stats,)
     if count_routed:
-        res += (jnp.concatenate(routed) if routed else jnp.zeros((0,), jnp.int32),)
+        counters = {"experts_routed": (jnp.concatenate(routed) if routed
+                                       else jnp.zeros((0,), jnp.int32))}
+        if rows:
+            counters["ep_rows"] = jnp.concatenate(rows)
+        res += (counters,)
     return res
 
 
@@ -272,9 +282,10 @@ def ragged_decode_step(
     ``return_routing=True`` appends the routing-stats tree (stats cover
     every slot row, active or not — padding rows route too; host side
     treats the per-tick stats as a load-shape sample, not exact counts);
-    ``return_routed=True`` appends the per-MoE-layer routed-expert counts
-    (``_run_segments``), which count padding rows' experts too: the step
-    reads those experts' weights."""
+    ``return_routed=True`` appends the dispatch's counters
+    (``_run_segments``): the per-MoE-layer routed-expert counts, which count
+    padding rows' experts too (the step reads those experts' weights), and
+    on an EP mesh the rows each rank's experts received."""
     x = embed_tokens(cfg, params, token)
     pos2d = positions.astype(jnp.int32)[:, None]
     x, new_caches, _, *tel = _run_segments(
@@ -363,7 +374,7 @@ def paged_ragged_decode_step(
     in the trash page); the per-slot leaves (window rings, SSM/LRU states,
     cross caches) get the same masked merge as ``ragged_decode_step``.
     ``return_routing=True`` appends the routing-stats tree and
-    ``return_routed=True`` the routed-expert counts, as there."""
+    ``return_routed=True`` the dispatch's counters, as there."""
     x = embed_tokens(cfg, params, token)
     pos2d = positions.astype(jnp.int32)[:, None]
     x, new_caches, _, *tel = _run_segments(

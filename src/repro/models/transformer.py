@@ -106,7 +106,9 @@ def apply_layer(
     """Returns (x, new_cache, aux_loss); with ``collect_stats`` or
     ``count_routed`` a 4th element — the MoE layer's telemetry dict (None
     for non-MoE layers): ``"stats"``, its jit-returnable ``RoutingStats``,
-    and ``"routed"``, the int32 count of experts its dispatch gave rows.
+    and ``"routed"``, the int32 count of experts its dispatch gave rows
+    (with ``"ep_rows"``, the rows each EP rank's experts received, on the
+    serving EP paths).
     The flags are static (part of the trace), so telemetry collection is
     decided when the caller builds its jitted step.  ``layer`` (paged
     decode): the index of this layer in its stacked page pools, which
@@ -158,7 +160,7 @@ def apply_layer(
             if collect_stats:
                 tel["stats"] = extra[0]
             if count_routed:
-                tel["routed"] = extra[-1]
+                tel.update(extra[-1])
         x = x + y
 
     if new_cache is not None and "self" in new_cache:

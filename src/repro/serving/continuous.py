@@ -459,10 +459,12 @@ class ContinuousEngine:
             self._c_spec_resyncs = M.counter("spec.draft_resyncs")
 
         def _telemetry(*tel):
-            # the decode step's third output: the routed-expert counts
-            # always (int32 [n_moe_layers], read back with the sampled
-            # tokens), the routing-stats tree when collection is on
-            out = {"experts_routed": tel[-1]}
+            # the decode step's third output: the dispatch's counters
+            # always (the routed-expert counts, int32 [n_moe_layers], and on
+            # an EP mesh the rows per rank, int32 [n_moe_layers, ep]; read
+            # back with the sampled tokens), the routing-stats tree when
+            # collection is on
+            out = {"counters": tel[-1]}
             if routing:
                 out["routing"] = tel[0]
             return out
@@ -1767,8 +1769,8 @@ class ContinuousEngine:
             nxt_dev = sample(logits, sub, temperature=self.temperature,
                              top_k=self.top_k, top_p=self.top_p)
             with self._phase("engine.wait"):
-                # analysis: allow(host-asarray) — THE per-tick sync: sampled tokens drive eos/budget/admission decisions on the host; the routed-expert counts ride in the same transfer
-                nxt, routed = jax.device_get((nxt_dev, tel["experts_routed"]))
+                # analysis: allow(host-asarray) — THE per-tick sync: sampled tokens drive eos/budget/admission decisions on the host; the dispatch's counters ride in the same transfer
+                nxt, counters = jax.device_get((nxt_dev, tel["counters"]))
         n_decoded = int(decoding.sum())
         t_tok = time.perf_counter()
         with self._phase("engine.commit"):
@@ -1795,13 +1797,13 @@ class ContinuousEngine:
         with self._phase("engine.record"):
             routing_m = summarize_routing(tel["routing"]) if tel.get("routing") else None
             self._record_metrics(n_decoded, t1 - t0, prefill_toks, n_active,
-                                 routing=routing_m, experts_routed=routed)
+                                 routing=routing_m, counters=counters)
         return n_active
 
     def _record_metrics(self, n_decoded: int, dt: float, prefill_toks: int = 0,
                         n_active: Optional[int] = None, *,
                         routing: Optional[dict] = None,
-                        experts_routed: Optional[np.ndarray] = None) -> None:
+                        counters: Optional[dict] = None) -> None:
         retraces = self.obs.watchdog.tick()
         if retraces:
             self._c_retraces.inc(retraces)
@@ -1837,11 +1839,12 @@ class ContinuousEngine:
             m["batched_prefill_occupancy"] = round(self._batched_occ_tick, 4)
             self._g_batch_occ.set(round(self._batched_occ_tick, 4))
             self._batched_occ_tick = 0.0
-        if experts_routed is not None:
-            # per MoE layer, in layer order: experts the decode step's
-            # dispatch gave at least one row (padding rows included) — whose
-            # weights the step read
-            m["experts_routed"] = experts_routed.tolist()
+        for k, v in (counters or {}).items():
+            # per MoE layer, in layer order: ``experts_routed``, the experts
+            # the decode step's dispatch gave at least one row (padding rows
+            # included) — whose weights the step read; on an EP mesh
+            # ``ep_rows``, the rows each rank's experts received
+            m[k] = v.tolist()
         if routing is not None:
             self._g_r_drop.set(routing["dropped_frac"])
             self._g_r_ent.set(routing["entropy"])

@@ -244,3 +244,96 @@ def test_paged_decode_step_keeps_pool_in_place(one_chip, monkeypatch):
     names = CUSTOM_CALL.findall(text)
     assert any(KERNELS["paged_decode_attn"].match(n) for n in names), names
     assert all(any(p.match(n) for p in KERNELS.values()) for n in names), names
+
+
+# An instruction's opcode and its op_name metadata, for the collectives.
+COLLECTIVE_OP = re.compile(
+    r'^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+(all-reduce|all-gather|all-to-all|collective-permute'
+    r'|reduce-scatter)(?:-start)?\([^\n]*?op_name="([^"]*)"', re.MULTILINE)
+HBM_BYTES = int(15.75 * 2**30)  # what XLA may use of a v5e chip's 16 GB
+
+
+def test_ep_serving_programs_fit_and_name_the_exchange(topo, monkeypatch):
+    """``nlg-350m-moe128-ep4`` as the four-chip cell serves it, whole (24
+    layers, 128 experts, 32 per chip) at its pool of 2,560 pages: the EP
+    decode step and the batched prefill compile for a (4,) mesh of v5e
+    chips within a chip's HBM; every collective inside the MoE layer's
+    shard_map carries the ``ep_exchange`` scope (the other collectives are
+    the slot-sharded attention's gathers); and the decode step keeps the
+    page pool in place, as one chip's does."""
+    import json
+
+    import repro.core.moe as moe
+    import repro.kernels.ops as ops
+    from repro.models import attention as attn
+    from repro.models import model as M
+    from repro.parallel.params import cache_pspecs
+    from repro.parallel.sharding import DEFAULT_RULES, make_mesh, use_mesh
+    from repro.serving.ep import serving_moe_impl
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench import program
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(attn, "PAGED_BACKEND", ["kernel"])
+    monkeypatch.setattr(moe, "GROUPED_EXPERT_BACKEND", ["kernel"])
+    conf = json.loads((root / "bench" / "configs" / "nlg-350m-moe128-ep4.json").read_text())
+    sv = conf["serving"]
+    cfg = program.model_config(conf)
+    assert sv["n_pages"] == 2560 and cfg.ep_mesh == (4,)
+    mesh = make_mesh(cfg.ep_mesh, (cfg.ep_axis,), devices=topo.devices[:4])
+    rules = {**DEFAULT_RULES, "expert": cfg.ep_axis, "batch": cfg.ep_axis}
+    run_cfg = cfg.replace(moe_impl=serving_moe_impl(cfg.moe_impl))
+    S, cap, ps, C = sv["slots"], sv["capacity"], sv["page_size"], sv["prefill_chunk"]
+    pt, mp = sv["n_pages"] + 1, -(-cap // ps)
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k), jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: M.init_paged_caches(cfg, S, cap, n_pages=sv["n_pages"],
+                                                        page_size=ps))
+    psh = program.param_shardings(cfg, mesh, rules, params)
+    params = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                          params, psh)
+    with use_mesh(mesh, rules):
+        cspec = cache_pspecs(mesh, caches, S)
+    caches = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+        caches, cspec, is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+    s = _sds(NamedSharding(mesh, P()))
+
+    def decode(params, tokens, positions, active, caches, tables):
+        return M.paged_ragged_decode_step(run_cfg, params, tokens, positions, active, caches,
+                                          tables, return_routed=True)
+
+    def prefill(params, tokens, positions, reset, active, last_idx, caches, tables):
+        return M.paged_prefill_chunk_batched(run_cfg, params, tokens, positions, reset, active,
+                                             last_idx, caches, tables, capacity=cap, page_size=ps)
+
+    progs = {
+        "decode": (jax.jit(decode, donate_argnums=(4,)),
+                   (params, s((S, 1), jnp.int32), s((S,), jnp.int32), s((S,), jnp.bool_), caches,
+                    s((S, mp), jnp.int32))),
+        "prefill": (jax.jit(prefill, donate_argnums=(6,)),
+                    (params, s((S, C), jnp.int32), s((S, C), jnp.int32), s((S,), jnp.bool_),
+                     s((S,), jnp.bool_), s((S,), jnp.int32), caches, s((S, mp), jnp.int32))),
+    }
+    texts = {}
+    for name, (fn, args) in progs.items():
+        with use_mesh(mesh, rules):
+            compiled = fn.lower(*args).compile()
+        ma = compiled.memory_analysis()
+        per_chip = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+                    + ma.temp_size_in_bytes)
+        assert per_chip < HBM_BYTES, (name, per_chip / 2**30)
+        text = texts[name] = compiled.as_text()
+        in_layer = [op_name for _, op_name in COLLECTIVE_OP.findall(text) if "shard_map" in op_name]
+        assert in_layer, f"{name}: no collective inside the MoE layer's shard_map"
+        assert all("ep_exchange" in n for n in in_layer), (name, in_layer)
+
+    moved = []
+    for name, _, dims, op in INSTR.findall(texts["decode"]):
+        dims = [int(d) for d in dims.split(",") if d]
+        if op in ("copy", "dynamic-slice", "dynamic-update-slice") and pt in dims \
+                and len(dims) > 2 and dims[-1] == cfg.num_kv_heads * cfg.head_dim:
+            moved.append(f"{op} {name} {dims}")
+    assert not moved, moved
